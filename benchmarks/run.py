@@ -21,6 +21,8 @@ import pathlib
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from benchmarks import (autotune_bench, bank_bench, common, fit_bench,
                         higher_order, kernels_bench, obs_bench,
                         pipeline_bench, regions_bench, roofline,
@@ -103,6 +105,7 @@ def main() -> None:
     as_json = "--json" in flags or as_check
     which = names or DEFAULT
     failures: list[str] = []
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name in which:
         common.drain_results()

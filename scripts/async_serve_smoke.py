@@ -26,6 +26,9 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.configs.siren import SirenConfig
     from repro.core import pipeline as P
     from repro.core.config import DEFAULT_CONFIG

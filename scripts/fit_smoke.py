@@ -29,6 +29,9 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.configs.siren import SirenConfig
     from repro.core.config import HardwareConfig
     from repro.fit import GradMSE, ValueMSE, compile_fit, fit

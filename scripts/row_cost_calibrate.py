@@ -106,6 +106,8 @@ def calibrate(rows: int = 4096, cols: int = 256, k: int = 256,
 
 
 def main(argv=None) -> int:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--rows", type=int, default=4096)
     ap.add_argument("--cols", type=int, default=256)

@@ -1,14 +1,17 @@
 """Store round-trip smoke gate (wired into scripts/ci.sh; `make serve-smoke`).
 
-Phase 1 (default): compile a small 2nd-order SIREN gradient pipeline,
-persist it to a temporary ArtifactStore, save the weights + query coords +
-expected outputs, then spawn a FRESH interpreter for phase 2.
+The parent process never imports JAX: it runs two children, one after the
+other, so that only one process at a time holds the device.
 
-Phase 2 (--restore DIR): in the fresh process, poison the tracer, rebuild
-the INR fn from the saved weights, and go through BOTH restore paths —
-``store.load(signature)`` and the ``compile_gradient(..., store=...)``
-disk-index hit — asserting zero tracer invocations and exact numeric parity
-with the expected outputs from the writer process.
+Write child (--write DIR): compile a small 2nd-order SIREN gradient
+pipeline, persist it to an ArtifactStore under DIR, and save the weights,
+query coords and expected outputs.
+
+Restore child (--restore DIR): in a fresh interpreter, poison the tracer,
+rebuild the INR fn from the saved weights, and go through BOTH restore
+paths — ``store.load(signature)`` and the ``compile_gradient(...,
+store=...)`` disk-index hit — asserting zero tracer invocations and exact
+numeric parity with the expected outputs from the write child.
 
   PYTHONPATH=src python scripts/serve_smoke.py
 """
@@ -42,8 +45,6 @@ def _setup():
 
 
 def write_phase(workdir: str) -> int:
-    import jax
-
     from repro.checkpoint import ckpt
     from repro.core import pipeline as P
     from repro.serve.store import ArtifactStore
@@ -58,23 +59,24 @@ def write_phase(workdir: str) -> int:
              q=np.asarray(q), **{f"out{i}": np.asarray(o)
                                  for i, o in enumerate(want)})
     with open(os.path.join(workdir, "meta.json"), "w") as f_:
-        json.dump({"signature": cg.signature, "n_outputs": len(want)}, f_)
+        json.dump({"signature": cg.signature, "n_outputs": len(want),
+                   "weight_sets": store.info()["weight_sets"]}, f_)
+    print(f"  [write] persisted {cg.signature}")
+    return 0
 
+
+def _child(phase: str, workdir: str) -> bool:
     env = dict(os.environ)
     env["PYTHONPATH"] = _src_path() + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     r = subprocess.run([sys.executable, os.path.abspath(__file__),
-                        "--restore", workdir],
+                        phase, workdir],
                        env=env, capture_output=True, text=True, timeout=420)
     sys.stdout.write(r.stdout)
     sys.stderr.write(r.stderr)
     if r.returncode != 0:
-        print("serve smoke FAILED in the restore subprocess")
-        return 1
-    print(f"serve smoke OK: signature {cg.signature}, "
-          f"{store.info()['weight_sets']} weight set(s), subprocess restored "
-          f"with zero tracer invocations and exact parity")
-    return 0
+        print(f"serve smoke FAILED in the {phase} subprocess")
+    return r.returncode == 0
 
 
 def _src_path() -> str:
@@ -134,10 +136,20 @@ def restore_phase(workdir: str) -> int:
 
 
 def main() -> int:
-    if len(sys.argv) >= 3 and sys.argv[1] == "--restore":
-        return restore_phase(sys.argv[2])
+    if len(sys.argv) >= 3 and sys.argv[1] in ("--write", "--restore"):
+        from repro.compile_cache import enable_compile_cache
+        enable_compile_cache()
+        phase = write_phase if sys.argv[1] == "--write" else restore_phase
+        return phase(sys.argv[2])
     with tempfile.TemporaryDirectory(prefix="inr-serve-smoke-") as workdir:
-        return write_phase(workdir)
+        if not (_child("--write", workdir) and _child("--restore", workdir)):
+            return 1
+        with open(os.path.join(workdir, "meta.json")) as f_:
+            meta = json.load(f_)
+    print(f"serve smoke OK: signature {meta['signature']}, "
+          f"{meta['weight_sets']} weight set(s), subprocess restored "
+          f"with zero tracer invocations and exact parity")
+    return 0
 
 
 if __name__ == "__main__":

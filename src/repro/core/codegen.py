@@ -25,7 +25,7 @@ from repro.core.segment import (SegmentPlan, build_segment_plan,
                                 segment_dispatch, _p)
 
 _OP_EXPR = {
-    "Mm": "{0} @ {1}",
+    "Mm": "jnp.matmul({0}, {1}, precision='highest')",
     "T": "{0}.T",
     "Sin": "jnp.sin({0})",
     "Cos": "jnp.cos({0})",

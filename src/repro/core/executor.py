@@ -33,7 +33,8 @@ def _eval_node(node: Node, args, block_b: int | None = None):
     if block_b is not None and len(shape) > 0:
         shape = (block_b, *shape[1:])
     if op == "Mm":
-        return args[0] @ args[1]
+        return jnp.matmul(args[0], args[1],
+                          precision=jax.lax.Precision.HIGHEST)
     if op == "T":
         return args[0].T
     if op == "Permute":

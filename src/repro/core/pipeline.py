@@ -97,13 +97,8 @@ class CompiledGradient:
                                if o not in plan.resident]
         # the one jitted block pipeline (serving granule) ...
         self._block_apply = jax.jit(self._make_block_fn())
-        # ... its chunked form (lax.map over config.chunk_blocks blocks);
-        # the chunk buffer is DONATED where the backend supports it, so
-        # steady-state serving reuses it instead of double-buffering every
-        # chunk in HBM ...
-        donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
-        self._chunk_apply = jax.jit(self._make_chunk_fn(),
-                                    donate_argnums=donate)
+        # ... its chunked form (lax.map over config.chunk_blocks blocks) ...
+        self._chunk_apply = jax.jit(self._make_chunk_fn())
         # ... and the classic full-plan-batch streaming execution
         self.apply = jax.jit(self._make_apply())
 

@@ -14,28 +14,15 @@ import math
 from dataclasses import dataclass, field
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """Version-compat ``jax.make_mesh``: newer jax grew
-    ``jax.sharding.AxisType`` and an ``axis_types`` kwarg (and made Explicit
-    the eventual default); older releases (<= 0.4.x) have neither.  Every
-    mesh here wants Auto axes, so pass ``axis_types=(Auto, ...)`` exactly
-    when the running jax supports it and let older versions take their
-    (equivalent) default."""
-    kw = {}
-    if devices is not None:
-        kw["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names), **kw)
-        except TypeError:      # AxisType exists but make_mesh predates kwarg
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules below
+    place arrays and let jit propagate, which Explicit axes would refuse."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 # Candidate mesh axes per logical axis, in preference order.  "fsdp" is a

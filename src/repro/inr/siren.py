@@ -35,7 +35,8 @@ def siren_apply(params: list[dict], x: jnp.ndarray, w0: float = 30.0):
     """x: [..., in] -> [..., out]."""
     h = x
     for i, layer in enumerate(params):
-        h = h @ layer["w"] + layer["b"]
+        h = jnp.dot(h, layer["w"],
+                    precision=jax.lax.Precision.HIGHEST) + layer["b"]
         if i < len(params) - 1:
             h = jnp.sin(w0 * h)
     return h

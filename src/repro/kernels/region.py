@@ -68,6 +68,10 @@ from jax.experimental import pallas as pl
 from repro.kernels.common import interpret_default
 from repro.kernels.fused_chain import eval_chain
 
+# f32 dots at full precision: at the TPU default (one bf16 pass) every
+# SIREN layer's w0 = 30 scale leaves gradients off by several percent
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 CHAIN = "chain"
 MM = "mm"
 CONCAT = "concat"
@@ -128,12 +132,20 @@ class RegionKernelSpec:
 
 
 def _eval_mm(x, w, bias, w0, apply_sin):
-    h = jnp.dot(x, w, preferred_element_type=jnp.float32)
+    h = jnp.dot(x, w, precision=_HIGHEST, preferred_element_type=jnp.float32)
     if bias is not None:
         h = h + bias
     if apply_sin:
         h = jnp.sin(w0 * h)
     return h
+
+
+def _as_2d(residents):
+    """Residents as the kernel sees them: a 1-D bias enters as one ``[1, N]``
+    row.  Mosaic tiles the last two dims of every operand, so a vmapped (or
+    K-stacked) ``[K, N]`` bias would put the lane axis K where the compiler
+    needs a tileable row dim; ``[K, 1, N]`` keeps ``(1, N)`` whole."""
+    return [r.reshape(1, -1) if r.ndim < 2 else r for r in residents]
 
 
 def _eval_group(env, res, group: TileGroup, member_steps, reducer_step):
@@ -167,10 +179,10 @@ def _eval_group(env, res, group: TileGroup, member_steps, reducer_step):
             else:
                 _, out, x, w, bias, w0, apply_sin = step
                 assert x not in members, "member MM lhs must be external"
-                b = res[bias][lo:hi] if bias is not None else None
+                b = res[bias][:, lo:hi] if bias is not None else None
                 tenv[out] = _eval_mm(env[x], res[w][:, lo:hi], b,
                                      w0, apply_sin)
-        part = jnp.dot(tenv[r_x], wfull[lo:hi, :],
+        part = jnp.dot(tenv[r_x], wfull[lo:hi, :], precision=_HIGHEST,
                        preferred_element_type=jnp.float32)
         acc = part if acc is None else acc + part
     if r_bias is not None:
@@ -254,6 +266,7 @@ def region_call(spec: RegionKernelSpec, stream, rows, residents, out_info, *,
         interpret = interpret_default()
     assert len(stream) == len(spec.stream_inputs), (spec, len(stream))
     assert len(rows) == len(spec.bcast_rows), (spec, len(rows))
+    residents = _as_2d(residents)
     R = stream[0].shape[0]
     br = min(bm, R)
     pad = (-R) % br
@@ -265,11 +278,7 @@ def region_call(spec: RegionKernelSpec, stream, rows, residents, out_info, *,
                 for a in stream]
     in_specs += [pl.BlockSpec((1, a.shape[1]), lambda i: (0, 0))
                  for a in rows]
-    for r in residents:
-        if r.ndim == 2:
-            in_specs.append(pl.BlockSpec(r.shape, lambda i: (0, 0)))
-        else:
-            in_specs.append(pl.BlockSpec(r.shape, lambda i: (0,)))
+    in_specs += [pl.BlockSpec(r.shape, lambda i: (0, 0)) for r in residents]
     out_specs = [pl.BlockSpec((br, c), lambda i: (i, 0))
                  for c, _ in out_info]
     out_shape = [jax.ShapeDtypeStruct((Rp, c), dt) for c, dt in out_info]
@@ -308,6 +317,7 @@ def region_call_stacked(spec: RegionKernelSpec, stream, rows, residents,
     assert len(stream) == len(spec.stream_inputs), (spec, len(stream))
     assert len(rows) == len(spec.bcast_rows), (spec, len(rows))
     K, R = stream[0].shape[0], stream[0].shape[1]
+    residents = [r.reshape(K, 1, -1) if r.ndim < 3 else r for r in residents]
     br = min(bm, R)
     pad = (-R) % br
     if pad:
@@ -398,6 +408,8 @@ def _region_bwd_call(spec: RegionKernelSpec, stream, rows, residents, cots, *,
     if interpret is None:
         interpret = interpret_default()
     ns, nb = len(stream), len(rows)
+    shapes = [r.shape for r in residents]
+    residents = _as_2d(residents)
     R = stream[0].shape[0]
     br = min(bm, R)
     pad = (-R) % br
@@ -410,11 +422,7 @@ def _region_bwd_call(spec: RegionKernelSpec, stream, rows, residents, cots, *,
                 for a in stream]
     in_specs += [pl.BlockSpec((1, a.shape[1]), lambda i: (0, 0))
                  for a in rows]
-    for r in residents:
-        if r.ndim == 2:
-            in_specs.append(pl.BlockSpec(r.shape, lambda i: (0, 0)))
-        else:
-            in_specs.append(pl.BlockSpec(r.shape, lambda i: (0,)))
+    in_specs += [pl.BlockSpec(r.shape, lambda i: (0, 0)) for r in residents]
     in_specs += [pl.BlockSpec((br, c.shape[1]), lambda i: (i, 0))
                  for c in cots]
 
@@ -426,10 +434,7 @@ def _region_bwd_call(spec: RegionKernelSpec, stream, rows, residents, cots, *,
         out_specs.append(pl.BlockSpec((1, a.shape[1]), lambda i: (0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((1, a.shape[1]), jnp.float32))
     for r in residents:
-        if r.ndim == 2:
-            out_specs.append(pl.BlockSpec(r.shape, lambda i: (0, 0)))
-        else:
-            out_specs.append(pl.BlockSpec(r.shape, lambda i: (0,)))
+        out_specs.append(pl.BlockSpec(r.shape, lambda i: (0, 0)))
         out_shape.append(jax.ShapeDtypeStruct(r.shape, jnp.float32))
 
     outs = pl.pallas_call(
@@ -444,7 +449,7 @@ def _region_bwd_call(spec: RegionKernelSpec, stream, rows, residents, cots, *,
         outs = (outs,)
     d_stream = tuple(o[:R] for o in outs[:ns])
     d_rows = tuple(outs[ns:ns + nb])
-    d_res = tuple(outs[ns + nb:])
+    d_res = tuple(o.reshape(s) for o, s in zip(outs[ns + nb:], shapes))
     return d_stream, d_rows, d_res
 
 

@@ -25,6 +25,7 @@ def _siren_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, k_steps: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == k_steps - 1)

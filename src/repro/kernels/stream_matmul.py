@@ -26,6 +26,7 @@ def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == k_steps - 1)

@@ -41,10 +41,11 @@ the large tensor — thousands of weight sets vs one small query block — so
 ``MultiINRArtifact(..., sharding=policy)`` shards the stacked [K] axis
 across the policy's mesh: every resident leaf is placed K-sharded
 (``ACT_RULES["inr"]``), query batches are placed with the SAME K axis
-sharded and the rows axis per-shard-local, and jit's SPMD partitioner
-splits the vmapped block pipeline into per-shard lanes with no cross-shard
-collective in the hot loop (each INR's serve is independent).  When K does
-not divide the mesh, the policy's divisibility fallback replicates —
+sharded and the rows axis per-shard-local, and ``shard_map`` runs the
+vmapped block pipeline over each device's own lanes with no cross-shard
+collective in the hot loop (each INR's serve is independent; the SPMD
+partitioner cannot split a Mosaic kernel, so the split is explicit).  When
+K does not divide the mesh, the policy's divisibility fallback replicates —
 identical numerics, no sharding.
 """
 
@@ -163,8 +164,8 @@ class MultiINRArtifact:
         # stack: resident leaves gain the [K] axis the block fn is vmapped over
         self.residents = {nid: jnp.stack([r[nid] for r in per_inr])
                           for nid in per_inr[0]}
-        # K-axis sharding: place every stacked resident before the jit below
-        # captures them, so the weight fleet lives sharded from the start
+        # K-axis sharding: place every stacked resident once, so the weight
+        # fleet lives sharded from the start
         self._k_sharding = self._resolve_k_sharding()
         if self._k_sharding is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -174,9 +175,15 @@ class MultiINRArtifact:
                 for nid, v in self.residents.items()}
         self.double_buffered = (bool(resident_double_buffer)
                                 and self._stacked_applicable())
-        self._serve = jax.jit(self._make_serve_stacked()
-                              if self.double_buffered
-                              else self._make_serve())
+        # the stacked residents enter as ARGUMENTS, not closure constants:
+        # constants would bake every lane's weights into the executable (on
+        # every device, whatever the K sharding) and recompile per stack
+        self._serve_fn = jax.jit(self._make_serve_stacked()
+                                 if self.double_buffered
+                                 else self._make_serve())
+
+    def _serve(self, xb):
+        return self._serve_fn(self.residents, xb)
 
     def _resolve_k_sharding(self):
         """(mesh, k_axis) when the policy shards the K axis, else None (no
@@ -209,11 +216,18 @@ class MultiINRArtifact:
     def _make_serve(self):
         vblock = jax.vmap(self.base.resident_block_fn(),
                           in_axes=(0,) + (0,) * len(self.base.plan.inputs))
-        residents = self.residents
 
-        def serve(xb):                 # [n_blocks, K, block, ...features]
-            return jax.lax.map(lambda b: vblock(residents, b), xb)
-        return serve
+        def serve(res, xb):            # [n_blocks, K, block, ...features]
+            return jax.lax.map(lambda b: vblock(res, b), xb)
+        if self._k_sharding is None:
+            return serve
+        # Mosaic kernels cannot be split by the SPMD partitioner, so a
+        # sharded fleet runs under shard_map: each device serves its own K/n
+        # lanes against its own slice of the stacked residents
+        from jax.sharding import PartitionSpec as P
+        mesh, ax = self._k_sharding
+        return jax.shard_map(serve, mesh=mesh, in_specs=(P(ax), P(None, ax)),
+                             out_specs=P(None, ax), check_vma=False)
 
     def _stacked_applicable(self) -> bool:
         """True when the whole pipeline can serve through the K-stacked
@@ -243,12 +257,11 @@ class MultiINRArtifact:
         cfg = base.config
         K = self.n_inrs
         B = plan.batch
-        residents = self.residents
         input_id = plan.inputs[0]
         regions = [u for _, u in base.region_plan.units()]
         streamed = self.streamed_outputs()
 
-        def stacked_row(nid):
+        def stacked_row(residents, nid):
             # one [K, 1, C] row per row-const extra (cf. executor's
             # per-lane [1, C] conversion)
             a = residents[nid]                     # [K, ...per-lane]
@@ -260,7 +273,7 @@ class MultiINRArtifact:
                 return a[:, None, :]
             return a.reshape(K, 1, 1)
 
-        def serve(xb):                 # [n_blocks, K, block, ...features]
+        def serve(residents, xb):      # [n_blocks, K, block, ...features]
             nb, _, block = xb.shape[:3]
             rows = nb * block
             env = {input_id: jnp.moveaxis(xb, 1, 0).reshape(
@@ -268,7 +281,7 @@ class MultiINRArtifact:
             for region in regions:
                 spec = region.spec
                 stream = [env[nid] for nid in region.stream_inputs]
-                row_args = [stacked_row(nid)
+                row_args = [stacked_row(residents, nid)
                             for nid, _ in region.bcast_rows]
                 bias_ids = {s[4] for s in spec.steps
                             if s[0] == "mm" and s[4] is not None}

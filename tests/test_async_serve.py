@@ -149,6 +149,33 @@ def test_drain_is_incremental(fleet, tmp_path):
     assert asyn.drain() == []
 
 
+def test_pending_take_reuses_one_slice_program():
+    """A long request taken chunk by chunk leaves its lane through slices
+    at a dynamic offset: the rows come out in order, and the compiles stay
+    a constant handful instead of one per remainder shape."""
+    from repro.serve.async_engine import _Pending
+
+    compiles = []
+
+    def on_duration(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    coords = jnp.arange(41 * 16 * 2, dtype=jnp.float32).reshape(-1, 2)
+    p = _Pending()
+    p.push(0, coords)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        taken = [p.take(16)[0] for _ in range(40)]
+        jax.block_until_ready(taken)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    np.testing.assert_array_equal(np.concatenate(taken),
+                                  np.asarray(coords[:640]))
+    assert p.rows == 16
+    assert len(compiles) <= 2, len(compiles)
+
+
 def test_empty_request_and_serve_async_empty(fleet, tmp_path):
     """A zero-row request never reaches a lane (it would change the lane
     count of the dispatch group) yet still gets a well-formed 0-row result

@@ -193,8 +193,11 @@ def test_kernel_col_tiling_parity_nonmultiple_width():
         + np.asarray(b2)
     np.testing.assert_allclose(np.asarray(out_u), want, rtol=1e-5,
                                atol=1e-5)
+    # the tiled reducer reassociates its K sum: parity is allclose-only,
+    # with the absolute floor scaled to the output's magnitude
+    scale = float(np.max(np.abs(np.asarray(out_u))))
     np.testing.assert_allclose(np.asarray(out_t), np.asarray(out_u),
-                               rtol=1e-5, atol=1e-5)
+                               rtol=1e-5, atol=1e-5 * scale)
 
 
 def test_scheduler_tiles_wide_region_under_tight_budget(wide_siren):
