@@ -1,0 +1,10 @@
+"""Share of the traced slice in which no operation ran on the device:
+1 - (union of the device's op intervals) / (slice length), in percent.
+Read from the profiler trace."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if t is None or not t.devices or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
