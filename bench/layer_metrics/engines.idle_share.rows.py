@@ -1,0 +1,11 @@
+"""Share of the traced slice in which the device idled while the host
+was inside a serving engine's span (``serve.group``, ``.pad``,
+``.dispatch``, ``.wait``, ``.unpad``) and no shorter program span, in
+percent. Read from the profiler trace (``span_split``); nothing where
+the slice holds no program span."""
+
+from span_split import idle_share
+
+
+def read(ctx):
+    return idle_share(ctx.trace, "serve.")

@@ -233,30 +233,39 @@ class CompiledGradient:
                 else jnp.zeros((0,) + tuple(self.graph.nodes[o].shape[1:]),
                                self.graph.nodes[o].dtype)
                 for o in self.graph.outputs)
-        pad = (-n) % block
-        if pad:
-            edge = jnp.broadcast_to(coords[-1:], (pad,) + coords.shape[1:])
-            coords = jnp.concatenate([coords, edge])
-        nb = coords.shape[0] // block
-        n_chunks = nb // chunk_blocks
+        # spans on the profiler's clock: each chunk and block span only
+        # enqueues device work, so device idle inside them is host dispatch
+        with TRACER.span("pipeline.pad", cat="pipeline"):
+            pad = (-n) % block
+            if pad:
+                edge = jnp.broadcast_to(coords[-1:],
+                                        (pad,) + coords.shape[1:])
+                coords = jnp.concatenate([coords, edge])
+            nb = coords.shape[0] // block
+            n_chunks = nb // chunk_blocks
+            if n_chunks:
+                head = coords[: n_chunks * chunk_blocks * block]
+                xc = head.reshape(n_chunks, chunk_blocks, block,
+                                  *coords.shape[1:])
 
         pieces: list[tuple] = []
-        if n_chunks:
-            head = coords[: n_chunks * chunk_blocks * block]
-            xc = head.reshape(n_chunks, chunk_blocks, block,
-                              *coords.shape[1:])
-            for c in range(n_chunks):
+        for c in range(n_chunks):
+            with TRACER.span("pipeline.chunk", cat="pipeline"):
                 outs = self._chunk_apply(xc[c])     # each [chunk, block, ...]
                 pieces.append(tuple(
                     o.reshape(chunk_blocks * block, *o.shape[2:])
                     for o in outs))
         for i in range(n_chunks * chunk_blocks, nb):
-            pieces.append(self._block_apply(coords[i * block:(i + 1) * block]))
+            with TRACER.span("pipeline.block", cat="pipeline"):
+                pieces.append(
+                    self._block_apply(coords[i * block:(i + 1) * block]))
 
-        streamed = iter(jnp.concatenate(col)[:n] if len(col) > 1
-                        else col[0][:n] for col in zip(*pieces))
-        return tuple(self._resident_output(o, n) if o in self.plan.resident
-                     else next(streamed) for o in self.graph.outputs)
+        with TRACER.span("pipeline.stitch", cat="pipeline"):
+            streamed = iter(jnp.concatenate(col)[:n] if len(col) > 1
+                            else col[0][:n] for col in zip(*pieces))
+            return tuple(self._resident_output(o, n)
+                         if o in self.plan.resident
+                         else next(streamed) for o in self.graph.outputs)
 
     def _resident_output(self, o: int, n: int):
         v = self.residents[o]

@@ -130,16 +130,21 @@ def fit(cf: CompiledFit, coords, targets, *, steps: int,
                      order=cf.order, loss=type(cf.loss).__name__):
         lv = tuple(leaves)
         for i in range(steps):
-            ts = time.perf_counter()
-            if chunks is None:
-                xc, yc, mc = xb, yb, mb
-            else:
-                idx = chunks[i]
-                xc, yc, mc = xb[idx], yb[idx], mb[idx]
-            lv, opt, loss = step_fn(lv, opt, i, xc, yc, mc)
-            losses.append(float(loss))
-            _FIT_STEPS.inc()
-            _LAT_STEP.observe(time.perf_counter() - ts)
+            with TRACER.span("fit.step", cat="fit"):
+                ts = time.perf_counter()
+                with TRACER.span("fit.gather", cat="fit"):
+                    if chunks is None:
+                        xc, yc, mc = xb, yb, mb
+                    else:
+                        idx = chunks[i]
+                        xc, yc, mc = xb[idx], yb[idx], mb[idx]
+                with TRACER.span("fit.dispatch", cat="fit"):
+                    lv, opt, loss = step_fn(lv, opt, i, xc, yc, mc)
+                with TRACER.span("fit.sync", cat="fit"):
+                    losses.append(float(loss))
+                # the step counts once its loss is on the host
+                _FIT_STEPS.inc()
+                _LAT_STEP.observe(time.perf_counter() - ts)
     wall = time.perf_counter() - t0
 
     final = cf.unflatten(lv)
@@ -225,17 +230,21 @@ def fit_many(cf: CompiledFit, params_list, coords, targets_list, *,
                      order=cf.order):
         lv = stacked
         for i in range(steps):
-            ts = time.perf_counter()
-            if chunks is None:
-                xc, yc, mc = xb, ybs, mb
-            else:
-                idx = chunks[i]
-                xc, yc, mc = xb[idx], ybs[:, idx], mb[idx]
-            lv, opt, loss = step_fn(lv, opt, i, xc, yc, mc)
-            for k in range(K):
-                losses[k].append(float(loss[k]))
-            _FIT_STEPS.inc(K)
-            _LAT_STEP.observe(time.perf_counter() - ts)
+            with TRACER.span("fit.step", cat="fit"):
+                ts = time.perf_counter()
+                with TRACER.span("fit.gather", cat="fit"):
+                    if chunks is None:
+                        xc, yc, mc = xb, ybs, mb
+                    else:
+                        idx = chunks[i]
+                        xc, yc, mc = xb[idx], ybs[:, idx], mb[idx]
+                with TRACER.span("fit.dispatch", cat="fit"):
+                    lv, opt, loss = step_fn(lv, opt, i, xc, yc, mc)
+                with TRACER.span("fit.sync", cat="fit"):
+                    for k in range(K):
+                        losses[k].append(float(loss[k]))
+                _FIT_STEPS.inc(K)
+                _LAT_STEP.observe(time.perf_counter() - ts)
     wall = time.perf_counter() - t0
 
     results = []

@@ -101,6 +101,7 @@ def fused_chain(x: jax.Array, chain, extras=(), *, block_rows: int = 256,
         out_specs=pl.BlockSpec((br, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, C), x.dtype),
         interpret=interpret,
+        name="fused_chain",
     )(x, *extras)
     return out[:R]
 

@@ -290,6 +290,7 @@ def region_call(spec: RegionKernelSpec, stream, rows, residents, out_info, *,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="region_fwd",
     )(*stream, *rows, *residents)
     if not isinstance(outs, (list, tuple)):
         outs = (outs,)
@@ -343,6 +344,7 @@ def region_call_stacked(spec: RegionKernelSpec, stream, rows, residents,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="region_fwd_stacked",
     )(*stream, *rows, *residents)
     if not isinstance(outs, (list, tuple)):
         outs = (outs,)
@@ -444,6 +446,7 @@ def _region_bwd_call(spec: RegionKernelSpec, stream, rows, residents, cots, *,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="region_bwd",
     )(*stream, *rows, *residents, *cots)
     if not isinstance(outs, (list, tuple)):
         outs = (outs,)

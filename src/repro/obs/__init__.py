@@ -8,8 +8,11 @@ Three pillars, one import:
     stats dicts (``ServingEngine.stats``, ``pipeline._STATS``,
     ``ArtifactStore.stats``) are read-through ``MetricsView``s over it.
   * **tracing** — the global ``TRACER`` of nestable spans around every
-    compile stage and serve phase, exportable as Chrome/Perfetto
-    trace-event JSON (``TRACER.export_chrome_json(path)`` then open at
+    compile stage, serve phase, block-pipeline chunk and fit step, each a
+    JAX profiler annotation (so a profiler trace holds them beside the
+    device's operations) and, when enabled, an in-memory record
+    exportable as Chrome/Perfetto trace-event JSON
+    (``TRACER.export_chrome_json(path)`` then open at
     https://ui.perfetto.dev).
   * **drift** — ``drift_report(cg)``: the compile-time cost model
     (predicted row-cycles, modeled HBM bytes/block, recorded on every

@@ -1,24 +1,30 @@
-"""Nestable span tracing with Chrome/Perfetto trace-event export.
+"""Nestable span tracing, on the JAX profiler's clock and in memory.
 
 Spans wrap host-side phases only — compile stages (trace → passes →
-segment plan → region plan → autoconfig → codegen) and serve phases
-(group → pad → dispatch → retire → unpad).  Nothing inside a jitted
-kernel can be spanned from Python; device time shows up as the duration
-of the host span that blocks on it.
+segment plan → region plan → autoconfig → codegen), serve phases
+(group → pad → dispatch → wait → unpad), the block pipeline's per-chunk
+enqueue (pad → chunk/block → stitch) and fit steps (gather → dispatch →
+sync).  Nothing inside a jitted kernel can be spanned from Python; device
+time shows up as the duration of the host span that blocks on it.
 
-The tracer is OFF by default.  When disabled, ``span()`` costs one
-attribute read and yields a shared null object — cheap enough to leave
-in every hot path (the obs benchmark gates total overhead at ≤5%).
-When enabled, each span records ``perf_counter_ns`` start/duration plus
+Every span enters ``jax.profiler.TraceAnnotation(name)``, so a profiler
+session (``jax.profiler.start_trace`` / ``trace``) records it beside the
+device's operations on one clock: device idle time can be assigned to the
+host phase that was open over it.  With no profiler session the
+annotation records nothing and costs about a microsecond.
+
+The in-memory record is OFF by default (``TRACER.enabled``).  When
+enabled, each span also records ``perf_counter_ns`` start/duration plus
 free-form args, and ``export_chrome()`` emits the standard trace-event
 JSON (``ph: "X"`` complete events, microsecond timestamps) that
-https://ui.perfetto.dev and chrome://tracing open directly.
+https://ui.perfetto.dev and chrome://tracing open directly.  Args stay in
+the in-memory record; the profiler sees only the span's name.
 
 Nesting is implicit: trace viewers reconstruct parent/child from
 containment of [ts, ts+dur) intervals per (pid, tid) track, so a
 ``serve.chunk`` span opened inside ``serve.drain`` renders nested
 without explicit parent ids.  Per-lane async phases pass ``tid=`` to get
-their own track.
+their own track in the in-memory record.
 """
 
 from __future__ import annotations
@@ -29,6 +35,8 @@ import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclass
@@ -42,8 +50,8 @@ class SpanEvent:
 
 
 class _NullSpan:
-    """What ``span()`` yields when tracing is disabled (and also when
-    enabled — the yielded handle only matters for ``set``)."""
+    """What ``span()`` yields when the in-memory record is disabled (the
+    yielded handle only matters for ``set``)."""
 
     __slots__ = ()
 
@@ -102,19 +110,22 @@ class Tracer:
 
     @contextmanager
     def span(self, name: str, cat: str = "obs", tid: int = 0, **args):
-        if not self.enabled:
-            yield _NULL
-            return
-        live_args = dict(args)
-        t0 = time.perf_counter_ns()
-        try:
-            yield _LiveSpan(live_args)
-        finally:
-            dur = time.perf_counter_ns() - t0
-            with self._lock:
-                self.events.append(
-                    SpanEvent(name=name, cat=cat, ts_ns=t0, dur_ns=dur,
-                              tid=tid, args=live_args))
+        """A span named ``name``: always a profiler annotation, and a
+        ``SpanEvent`` in memory while the tracer is enabled."""
+        with TraceAnnotation(name):
+            if not self.enabled:
+                yield _NULL
+                return
+            live_args = dict(args)
+            t0 = time.perf_counter_ns()
+            try:
+                yield _LiveSpan(live_args)
+            finally:
+                dur = time.perf_counter_ns() - t0
+                with self._lock:
+                    self.events.append(
+                        SpanEvent(name=name, cat=cat, ts_ns=t0, dur_ns=dur,
+                                  tid=tid, args=live_args))
 
     def instant(self, name: str, cat: str = "obs", tid: int = 0, **args):
         """Zero-duration marker (renders as a tick on the timeline)."""

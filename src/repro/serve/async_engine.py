@@ -19,7 +19,7 @@ This module overlaps those phases (DESIGN.md §8):
     (non-blocking).  Retirement only WAITS on device results — the
     host-side unpad/scatter of a retired chunk is deferred until right
     after the NEXT dispatch launches, so that host work overlaps the new
-    chunk's device execution (``stats["host_unpad_s"]`` times it).
+    chunk's device execution (the ``serve.unpad`` span shows it).
   * ``drain()`` flushes the remainders (full blocks through the jitted
     block step, one final padded block), retires everything in flight, and
     returns results for every outstanding ticket IN SUBMISSION ORDER.
@@ -71,8 +71,6 @@ _ASYNC_METRICS = {
                    "lane admissions at chunk boundaries"),
     "evictions": ("serve_evictions", "lane evictions at chunk boundaries"),
     "max_inflight": ("serve_max_inflight", "peak dispatch queue depth"),
-    "host_unpad_s": ("serve_host_unpad_s",
-                     "host time unpadding retired chunks (overlapped)"),
 }
 
 # per-request latency histograms (DESIGN.md §10): queue-wait is the time a
@@ -212,7 +210,6 @@ class AsyncServingEngine(ServingEngine):
                 lanes[inr_id] = _Pending()
                 self.stats["admissions"] += 1
             lanes[inr_id].push(ticket, coords)
-        self.stats["host_group_s"] += time.perf_counter() - t0
         return ticket
 
     def _enqueue_bank(self, fid: str, coords, t0: float) -> int:
@@ -231,7 +228,6 @@ class AsyncServingEngine(ServingEngine):
                 self._bank_pending[sig] = _Pending()
                 self.stats["admissions"] += 1
             self._bank_pending[sig].push(ticket, coords)
-        self.stats["host_group_s"] += time.perf_counter() - t0
         return ticket
 
     def submit(self, inr_id: str, coords) -> int:
@@ -362,14 +358,12 @@ class AsyncServingEngine(ServingEngine):
                                chunk_rows: int) -> None:
         with TRACER.span("serve.chunk", cat="serve", sig=sig[:12],
                          rows=chunk_rows):
-            t0 = time.perf_counter()
             cg = self._artifact(sig)
             block = cg.config.block
             with TRACER.span("serve.pad", cat="serve"):
                 coords, scatter = p.take(chunk_rows)
                 xc = coords.reshape(chunk_rows // block, block,
                                     *coords.shape[1:])
-            self.stats["host_group_s"] += time.perf_counter() - t0
             self.stats["async_chunks"] += 1
             self.stats["rows"] += chunk_rows
             with TRACER.span("serve.dispatch", cat="serve"):
@@ -385,7 +379,6 @@ class AsyncServingEngine(ServingEngine):
         block = cg.config.block
         while p.rows:
             with TRACER.span("serve.block", cat="serve", sig=sig[:12]):
-                t0 = time.perf_counter()
                 n = min(block, p.rows)
                 with TRACER.span("serve.pad", cat="serve"):
                     coords, scatter = p.take(n)
@@ -393,7 +386,6 @@ class AsyncServingEngine(ServingEngine):
                         coords = pad_rows(coords, block)
                 self.stats["rows"] += n
                 self.stats["padded_rows"] += block - n
-                self.stats["host_group_s"] += time.perf_counter() - t0
                 self.stats["async_blocks"] += 1
                 with TRACER.span("serve.dispatch", cat="serve"):
                     outs = cg.apply_block(coords)
@@ -405,7 +397,6 @@ class AsyncServingEngine(ServingEngine):
         K lanes are the INRs admitted at this boundary."""
         with TRACER.span("serve.chunk.multi", cat="serve", sig=sig[:12],
                          lanes=len(active)):
-            t0 = time.perf_counter()
             cg = self._artifact(sig)
             block = cg.config.block
             take = nb * block
@@ -427,7 +418,6 @@ class AsyncServingEngine(ServingEngine):
             xb = jnp.moveaxis(
                 batch.reshape(len(active), nb, block, *batch.shape[2:]),
                 0, 1)
-            self.stats["host_group_s"] += time.perf_counter() - t0
             self.stats["async_multi_chunks"] += 1
             if m.k_sharded:
                 self.stats["k_sharded_batches"] += 1
@@ -443,12 +433,10 @@ class AsyncServingEngine(ServingEngine):
         (request k for filter j later reads its row slice of output j)."""
         with TRACER.span("serve.chunk.bank", cat="serve", sig=sig[:12],
                          rows=p.rows):
-            t0 = time.perf_counter()
             bank = self._bank(sig)
             n = p.rows
             with TRACER.span("serve.pad", cat="serve"):
                 coords, scatter = p.take(n)
-            self.stats["host_group_s"] += time.perf_counter() - t0
             self.stats["bank_groups"] += 1
             self.stats["rows"] += n
             self.stats["padded_rows"] += (-n) % bank.cg.config.block
@@ -471,27 +459,22 @@ class AsyncServingEngine(ServingEngine):
         DEFERRED: ``_dispatch`` runs it right after launching the next
         chunk, so unpadding retired results overlaps that chunk's device
         execution instead of sitting on the critical path."""
-        t0 = time.perf_counter()
-        wait = t0 - item.t_dispatch
-        self.stats["queue_wait_s"] += wait
-        _LAT_QUEUE.observe(wait, engine=self.stats.labels["engine"])
+        _LAT_QUEUE.observe(time.perf_counter() - item.t_dispatch,
+                           engine=self.stats.labels["engine"])
         with TRACER.span("serve.retire", cat="serve", kind=item.kind,
                          rows=item.rows):
             jax.block_until_ready(item.outs)
-        self.stats["device_exec_s"] += time.perf_counter() - t0
         self._retired.append(item)
 
     def _unpad_retired(self) -> None:
         """Scatter every retired item's rows into its tickets (dropping
-        padding — it never reaches a caller), timed as ``host_unpad_s``."""
+        padding — it never reaches a caller), inside ``serve.unpad``."""
         if not self._retired:
             return
-        t0 = time.perf_counter()
         with TRACER.span("serve.unpad", cat="serve",
                          items=len(self._retired)):
             while self._retired:
                 self._scatter_item(self._retired.popleft())
-        self.stats["host_unpad_s"] += time.perf_counter() - t0
 
     def _scatter_item(self, item: _InFlight) -> None:
         if item.kind == "multi":
@@ -587,8 +570,6 @@ class AsyncServingEngine(ServingEngine):
     def describe(self) -> str:
         st = self.stats
         return (super().describe()
-                + f"\n  async phases: host_unpad "
-                f"{st['host_unpad_s'] * 1e3:.1f}ms (overlapped)"
                 + f"\n  async: inflight<= {self.inflight} "
                 f"(peak {st['max_inflight']}), "
                 f"{st['async_chunks']} chunks / {st['async_blocks']} blocks "

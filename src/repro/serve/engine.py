@@ -51,10 +51,11 @@ multi-INR artifacts) are LRU with configurable capacities
 are only evicted when a store is attached (they reload on demand); with no
 store the payload cache grows unbounded rather than lose weights.
 
-Perf counters.  ``stats`` carries wall-clock phase totals so the async
-overlap win is observable: ``host_group_s`` (request grouping + padding),
-``device_exec_s`` (blocked-on-device time), ``queue_wait_s`` (async only:
-time dispatched work sat in the in-flight queue before retrieval).
+Phases.  ``serve`` opens ``serve.group``, ``serve.pad``,
+``serve.dispatch`` (enqueue only), ``serve.wait`` (blocked on the device)
+and ``serve.unpad`` spans (``obs.tracing``); under a JAX profiler session
+they land on the device trace's clock, so device idle time can be
+assigned to the phase the host was in.
 """
 
 from __future__ import annotations
@@ -94,12 +95,6 @@ _SERVE_METRICS = {
                           "weight payloads evicted from the LRU"),
     "multi_evictions": ("serve_multi_evictions",
                         "multi-INR stacks evicted from the LRU"),
-    "host_group_s": ("serve_host_group_s",
-                     "host time grouping and padding requests"),
-    "device_exec_s": ("serve_device_exec_s",
-                      "time blocked on device execution"),
-    "queue_wait_s": ("serve_queue_wait_s",
-                     "async: time work sat in the in-flight queue"),
 }
 
 
@@ -381,7 +376,6 @@ class ServingEngine:
         signature group is grouped, padded, dispatched, and BLOCKED on
         before the next (the baseline the async engine overlaps)."""
         t_batch = time.perf_counter()
-        t0 = t_batch
         requests = list(requests)
         self.stats["requests"] += len(requests)
         results: list = [None] * len(requests)
@@ -407,18 +401,14 @@ class ServingEngine:
             for inr_id in per_inr:
                 sig, _ = self._routes[inr_id]
                 by_sig.setdefault(sig, []).append(inr_id)
-        self.stats["host_group_s"] += time.perf_counter() - t0
 
         for sig, inr_ids in by_sig.items():
             self.stats["groups"] += 1
-            t0 = time.perf_counter()
             with TRACER.span("serve.pad", cat="serve", sig=sig[:12]):
                 coords_per_inr = {
                     i: (jnp.concatenate([c for _, c in per_inr[i]])
                         if len(per_inr[i]) > 1 else per_inr[i][0][1])
                     for i in inr_ids}
-            self.stats["host_group_s"] += time.perf_counter() - t0
-            t0 = time.perf_counter()
             with TRACER.span("serve.dispatch", cat="serve", sig=sig[:12],
                              inrs=len(inr_ids)):
                 if len(inr_ids) == 1:
@@ -426,8 +416,8 @@ class ServingEngine:
                         sig, inr_ids[0], coords_per_inr[inr_ids[0]])}
                 else:
                     outs = self._serve_multi(sig, inr_ids, coords_per_inr)
+            with TRACER.span("serve.wait", cat="serve", sig=sig[:12]):
                 jax.block_until_ready(outs)
-            self.stats["device_exec_s"] += time.perf_counter() - t0
             with TRACER.span("serve.unpad", cat="serve", sig=sig[:12]):
                 for inr_id in inr_ids:
                     row = 0
@@ -444,21 +434,18 @@ class ServingEngine:
         for sig, items in bank_groups.items():
             self.stats["groups"] += 1
             self.stats["bank_groups"] += 1
-            t0 = time.perf_counter()
             with TRACER.span("serve.pad", cat="serve", sig=sig[:12]):
                 coords = (jnp.concatenate([c for _, _, c in items])
                           if len(items) > 1 else items[0][2])
-            self.stats["host_group_s"] += time.perf_counter() - t0
             bank = self._bank(sig)
             self.stats["rows"] += int(coords.shape[0])
             self.stats["padded_rows"] += \
                 (-int(coords.shape[0])) % bank.cg.config.block
-            t0 = time.perf_counter()
             with TRACER.span("serve.dispatch", cat="serve", sig=sig[:12],
                              bank=True):
                 outs = bank.apply_batched(self._place(coords, 0))
+            with TRACER.span("serve.wait", cat="serve", sig=sig[:12]):
                 jax.block_until_ready(outs)
-            self.stats["device_exec_s"] += time.perf_counter() - t0
             with TRACER.span("serve.unpad", cat="serve", sig=sig[:12]):
                 row = 0
                 for k, j, c in items:
@@ -522,10 +509,7 @@ class ServingEngine:
                  f"devices={n_dev}"
                  + (f" [per-shard chunking]" if self.shard_chunking
                     and n_dev > 1 else ""),
-                 f"  stats: {st}",
-                 f"  phases: host_group {st['host_group_s'] * 1e3:.1f}ms | "
-                 f"device_exec {st['device_exec_s'] * 1e3:.1f}ms | "
-                 f"queue_wait {st['queue_wait_s'] * 1e3:.1f}ms"]
+                 f"  stats: {st}"]
         for inr_id in sorted(self._routes):
             sig, wid = self._routes[inr_id]
             lines.append(f"  {inr_id} -> {sig} / {wid}")

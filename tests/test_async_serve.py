@@ -231,24 +231,54 @@ def test_payloads_not_evicted_without_store(fleet):
     assert e.stats["payload_evictions"] == 0
 
 
-def test_perf_counters_populate(fleet, tmp_path):
-    """Wall-clock phase counters move on both paths and show in
-    describe()."""
+def test_perf_counters_populate(fleet, tmp_path, monkeypatch):
+    """Both paths open their phase spans (the sync path enqueues, then
+    blocks only inside ``serve.wait``; the async path retires what it
+    dispatched), the queue-wait histogram moves on the async path only,
+    and describe() reports the async queue."""
+    import time
+
+    from repro.obs.metrics import REGISTRY
+    from repro.obs.tracing import TRACER
     cfg, cgs, other = fleet
     sync = _register(ServingEngine(tmp_path / "s"), cgs, other)
     asyn = _register(AsyncServingEngine(tmp_path / "a"), cgs, other)
     q = jax.random.uniform(jax.random.PRNGKey(7),
                            (40, cfg.in_features), jnp.float32, -1, 1)
-    sync.serve([("i0", q), ("i1", q)])
-    assert sync.stats["host_group_s"] > 0
-    assert sync.stats["device_exec_s"] > 0
-    assert sync.stats["queue_wait_s"] == 0, "sync path never queues"
-    asyn.serve_async([("i0", q), ("i1", q)])
-    assert asyn.stats["host_group_s"] > 0
-    assert asyn.stats["queue_wait_s"] > 0
-    for text in (sync.describe(), asyn.describe()):
-        assert "host_group" in text and "device_exec" in text \
-            and "queue_wait" in text
+    blocks = []
+    block = jax.block_until_ready
+
+    def timed_block(x):
+        blocks.append(time.perf_counter_ns())
+        return block(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", timed_block)
+    TRACER.clear()
+    with TRACER.enabled_scope():
+        sync.serve([("i0", q), ("i1", q)])
+        sync_spans = TRACER.span_names()
+        waits = [(e.ts_ns, e.ts_ns + e.dur_ns) for e in TRACER.events
+                 if e.name == "serve.wait"]
+        TRACER.clear()
+        asyn.serve_async([("i0", q), ("i1", q)])
+        async_spans = TRACER.span_names()
+    TRACER.clear()
+    serve_spans = [n for n in sync_spans if n.startswith("serve.")]
+    assert serve_spans[:4] == ["serve.group", "serve.pad", "serve.dispatch",
+                               "serve.wait"]
+    sync_blocks = [t for t in blocks if t <= waits[-1][1]]
+    assert sync_blocks and all(any(a <= t <= b for a, b in waits)
+                               for t in sync_blocks)
+    assert "serve.unpad" in sync_spans and "serve.retire" not in sync_spans
+    assert {"serve.pad", "serve.dispatch", "serve.retire",
+            "serve.unpad"} <= set(async_spans)
+    assert "serve.wait" not in async_spans
+    waits = REGISTRY.get("serve_queue_wait_latency_s")
+    assert waits.count(engine=sync.stats.labels["engine"]) == 0
+    assert waits.count(engine=asyn.stats.labels["engine"]) > 0
+    for e in (sync, asyn):
+        assert not {"host_group_s", "device_exec_s", "queue_wait_s",
+                    "host_unpad_s"} & set(e.stats)
     assert "async: inflight" in asyn.describe()
 
 

@@ -3,6 +3,7 @@ views, deterministic histogram percentiles, span tracing with Perfetto
 export, model-vs-measured drift reports, FIFO high-water headroom, the
 structured launch logger, and the ≤5% serve-overhead gate."""
 
+import glob
 import json
 
 import jax
@@ -217,6 +218,111 @@ def test_serve_async_trace_has_nested_serve_spans(small_inr, tmp_path):
     assert "serve.dispatch" in names and "serve.pad" in names
     doc = json.loads(TRACER.export_chrome_json())
     assert all(e["ph"] == "X" for e in doc["traceEvents"])
+
+
+def _profiled(fn, trace_dir):
+    """Run ``fn`` under a JAX profiler session; the program's spans the
+    trace holds on its host planes, as (name, start_ns, end_ns) in start
+    order."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(trace_dir / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    data = ProfileData.from_file(path)
+    return sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for plane in data.planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for e in line.events
+                   if e.name.startswith(("pipeline.", "serve.", "fit."))),
+                  key=lambda s: s[1])
+
+
+def _inside(inner, outer) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_serve_spans_reach_the_profiler(small_inr, tmp_path):
+    """With the in-memory record off, a profiler session still gets every
+    engine and pipeline span: one ``pipeline.chunk`` per full chunk, each
+    inside ``serve.dispatch``, and ``serve.wait`` after the enqueue."""
+    cfg, f, x = small_inr
+    cg = P.compile_gradient(f, 1, x, config=HW)
+    eng = ServingEngine()
+    eng.register("i0", cg)
+    q = jax.random.uniform(jax.random.PRNGKey(8),
+                           (75, cfg.in_features), jnp.float32, -1, 1)
+    eng.serve([("i0", q)])                         # compile outside the trace
+    spans = _profiled(lambda: eng.serve([("i0", q)]), tmp_path / "prof")
+    assert TRACER.events == [], "the in-memory record stays off"
+    names = [s[0] for s in spans]
+    assert {"serve.group", "serve.pad", "serve.dispatch", "serve.wait",
+            "serve.unpad", "pipeline.pad", "pipeline.chunk",
+            "pipeline.stitch"} <= set(names)
+    block, chunk = cg.config.block, cg.config.block * cg.config.chunk_blocks
+    n_blocks = -(-75 // block)
+    chunks = [s for s in spans if s[0] == "pipeline.chunk"]
+    assert len(chunks) == 75 // chunk >= 1
+    assert names.count("pipeline.block") == n_blocks - len(chunks) \
+        * cg.config.chunk_blocks
+    (dispatch,) = [s for s in spans if s[0] == "serve.dispatch"]
+    (wait,) = [s for s in spans if s[0] == "serve.wait"]
+    assert all(_inside(c, dispatch) for c in chunks)
+    assert dispatch[2] <= wait[1], "the wait opens once the work is enqueued"
+
+
+def test_fit_step_spans_nest_and_count(tmp_path, monkeypatch):
+    """Each fit step opens ``fit.step`` holding ``fit.gather``,
+    ``fit.dispatch`` and ``fit.sync`` in that order; the loss reaches the
+    host inside ``fit.sync``, and the step counts on ``fit_steps`` after
+    it, still inside ``fit.step``."""
+    import time
+
+    import repro.fit.engine as fit_engine
+    from repro.fit import ValueMSE, compile_fit, fit
+    syncs, counts = [], []
+
+    def timed_float(x):
+        if isinstance(x, jax.Array):           # a device value to the host
+            syncs.append(time.perf_counter_ns())
+        return float(x)
+
+    inc = fit_engine._FIT_STEPS.inc
+
+    def timed_inc(*a, **kw):
+        counts.append(time.perf_counter_ns())
+        return inc(*a, **kw)
+
+    monkeypatch.setattr(fit_engine, "float", timed_float, raising=False)
+    monkeypatch.setattr(fit_engine._FIT_STEPS, "inc", timed_inc)
+    cfg = SirenConfig(hidden_features=16, hidden_layers=1)
+    params = siren_init(cfg, jax.random.PRNGKey(0))
+    coords = jax.random.uniform(jax.random.PRNGKey(9),
+                                (40, cfg.in_features), jnp.float32, -1, 1)
+    target = jnp.tanh(3.0 * coords[:, :1])
+    cf = compile_fit(siren_fn(cfg, params), ValueMSE(), 1, coords[:16],
+                     params=params, config=HW)
+    steps = REGISTRY.get("fit_steps")
+    before = steps.value()
+    with TRACER.enabled_scope():
+        spans = _profiled(lambda: fit(cf, coords, target, steps=3,
+                                      batch_rows=16), tmp_path / "prof")
+    assert steps.value() - before == 3
+    step_spans = [s for s in spans if s[0] == "fit.step"]
+    assert len(step_spans) == 3
+    for st in step_spans:
+        kids = [s[0] for s in spans if s[0] != "fit.step" and _inside(s, st)]
+        assert kids == ["fit.gather", "fit.dispatch", "fit.sync"]
+    # the same spans on the host clock, against the loss reads and counts
+    mem = {n: [(e.ts_ns, e.ts_ns + e.dur_ns) for e in TRACER.events
+               if e.name == n] for n in ("fit.step", "fit.sync")}
+    assert len(syncs) == len(counts) == 3
+    for (a, b), (sa, sb), t_sync, t_count in zip(
+            mem["fit.step"], mem["fit.sync"], syncs, counts):
+        assert sa <= t_sync <= sb <= t_count <= b
 
 
 # ---------------------------------------------------------------------------

@@ -85,6 +85,7 @@ def test_serving_chunk_step_compiles(order, seed_siren, one_chip,
                     cfg.in_features), one_chip)
     text = jax.jit(cg._make_chunk_fn()).lower(chunk).compile().as_text()
     assert "tpu_custom_call" in text
+    assert "%region_fwd." in text, "the kernel's name is its instruction's"
 
 
 def test_fit_stream_value_and_grad_compiles(seed_siren, one_chip,
@@ -103,6 +104,9 @@ def test_fit_stream_value_and_grad_compiles(seed_siren, one_chip,
     text = jax.jit(cf._stream_vg).lower(leaves, coords,
                                         targets).compile().as_text()
     assert text.count("tpu_custom_call") >= 2     # forward + backward
+    # under autodiff the names read jvp_region_fwd_ and
+    # transpose_jvp_region_bwd__
+    assert "region_fwd" in text and "region_bwd" in text
 
 
 def test_order3_standalone_fused_chain_compiles(seed_siren, one_chip,
@@ -124,4 +128,4 @@ def test_order3_standalone_fused_chain_compiles(seed_siren, one_chip,
         return fused_chain(x, spec.steps, tuple(extras),
                            block_rows=cg.config.bm)
     text = jax.jit(call).lower(x, *extras).compile().as_text()
-    assert "tpu_custom_call" in text
+    assert "tpu_custom_call" in text and "%fused_chain." in text
