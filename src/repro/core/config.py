@@ -13,7 +13,7 @@ source of truth that every layer reads:
     executor._run_segment                   -> kernel tile hints
     codegen.emit_python                     -> emitted source records it
     dataflow.map_to_dataflow / fifo_opt     -> FIFO granule, MM ii, alpha
-    CompiledGradient.apply_batched          -> serving chunk size
+    serve.AsyncServingEngine                -> admission chunk size
 
 The object is frozen and hashable, so it IS the compile-cache key: two
 artifacts differ exactly when their resolved configs (or graphs) differ.
@@ -34,9 +34,11 @@ class HardwareConfig:
     * ``block``            — rows per pipeline step: the batch dim is split
                              into blocks of this many rows for the streaming
                              executor and the serving path (DESIGN.md §2).
-    * ``chunk_blocks``     — serving granule: ``apply_batched`` streams full
-                             chunks of this many blocks through one jitted
-                             ``lax.map``; the remainder goes block-by-block.
+    * ``chunk_blocks``     — the async engine's admission granule: it
+                             streams full chunks of this many blocks through
+                             one jitted ``lax.map`` and admits new requests
+                             between them (``apply_batched`` splits a whole
+                             request into binary parts instead).
     * ``dataflow_block``   — FIFO granule (elements per block) of the
                              dataflow model: ``Stream.n_blocks`` and the
                              deadlock/latency analysis count in these units.

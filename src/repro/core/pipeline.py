@@ -55,6 +55,12 @@ from repro.obs.metrics import MetricsView, counter as _obs_counter
 from repro.obs.tracing import TRACER
 
 
+def binary_parts(nb: int) -> list[int]:
+    """The powers of two that sum to ``nb``, largest first (10 -> [8, 2]):
+    the block counts ``apply_batched`` dispatches a request in."""
+    return [1 << b for b in reversed(range(nb.bit_length())) if nb >> b & 1]
+
+
 class CompiledGradient:
     """Frozen compile-once / run-many pipeline artifact.
 
@@ -97,7 +103,8 @@ class CompiledGradient:
                                if o not in plan.resident]
         # the one jitted block pipeline (serving granule) ...
         self._block_apply = jax.jit(self._make_block_fn())
-        # ... its chunked form (lax.map over config.chunk_blocks blocks) ...
+        # ... its lax.map over a leading block axis, one trace per map
+        # length (apply_batched's binary parts, the async engine's chunks) ...
         self._chunk_apply = jax.jit(self._make_chunk_fn())
         # ... and the classic full-plan-batch streaming execution
         self.apply = jax.jit(self._make_apply())
@@ -186,15 +193,15 @@ class CompiledGradient:
         """One jitted CHUNK step of the serving path: ``xchunk`` is
         [n_blocks, block, ...features] already split into blocks; returns the
         streamed outputs, each [n_blocks, block, ...].  This is the granule
-        the async serving engine's continuous-batching loop dispatches —
-        the per-chunk loop of ``apply_batched`` lifted out so ADMISSION can
-        happen between chunks (DESIGN.md §8).  Shape-stable callers (full
-        ``config.chunk_blocks`` chunks) hit one compiled trace."""
+        the async serving engine's continuous-batching loop dispatches, so
+        ADMISSION can happen between chunks (DESIGN.md §8).  Shape-stable
+        callers (full ``config.chunk_blocks`` chunks) hit one compiled
+        trace."""
         return self._chunk_apply(xchunk)
 
     def apply_block(self, xblk):
         """One jitted BLOCK step ([block, ...features] -> streamed outs) —
-        the remainder granule of the serving path."""
+        the async engine's remainder granule."""
         return self._block_apply(xblk)
 
     def streamed_outputs(self) -> list[int]:
@@ -212,13 +219,13 @@ class CompiledGradient:
 
         ``coords`` is [N, ...features] for any N: the batch is padded to a
         block multiple (edge rows replicated — padding never reaches the
-        caller), full chunks of ``config.chunk_blocks`` blocks stream through
-        one jitted ``lax.map``, remainder blocks through the jitted per-block
-        pipeline, and the first N rows of each output are returned.  The
-        chunk size is part of the artifact's HardwareConfig, so exactly two
-        traces compile per artifact, no matter how many batch sizes are
-        served — a different chunking is a different (cached) artifact, not a
-        retrace of this one.
+        caller) and reshaped once to its ``nb`` blocks; ``nb`` streams as
+        its binary parts, largest first, each ONE call of the jitted block
+        ``lax.map`` (75 rows at block 8: 10 blocks = 8 + 2, two calls).
+        So a request makes at most ``popcount(nb)`` dispatches, an artifact
+        compiles at most ``floor(log2(nb_max)) + 1`` map lengths however
+        many batch sizes it serves, and no row is computed beyond the block
+        pad.  The first N rows of each output are returned.
         """
         if len(self.plan.inputs) != 1:
             raise ValueError("apply_batched serves single-input (coordinate) "
@@ -226,15 +233,14 @@ class CompiledGradient:
         coords = jnp.asarray(coords)
         n = coords.shape[0]
         block = self.config.block
-        chunk_blocks = self.config.chunk_blocks
         if n == 0:
             return tuple(
                 self._resident_output(o, 0) if o in self.plan.resident
                 else jnp.zeros((0,) + tuple(self.graph.nodes[o].shape[1:]),
                                self.graph.nodes[o].dtype)
                 for o in self.graph.outputs)
-        # spans on the profiler's clock: each chunk and block span only
-        # enqueues device work, so device idle inside them is host dispatch
+        # spans on the profiler's clock: each chunk span only enqueues
+        # device work, so device idle inside one is host dispatch
         with TRACER.span("pipeline.pad", cat="pipeline"):
             pad = (-n) % block
             if pad:
@@ -242,27 +248,23 @@ class CompiledGradient:
                                         (pad,) + coords.shape[1:])
                 coords = jnp.concatenate([coords, edge])
             nb = coords.shape[0] // block
-            n_chunks = nb // chunk_blocks
-            if n_chunks:
-                head = coords[: n_chunks * chunk_blocks * block]
-                xc = head.reshape(n_chunks, chunk_blocks, block,
-                                  *coords.shape[1:])
+            xb = coords.reshape(nb, block, *coords.shape[1:])
 
         pieces: list[tuple] = []
-        for c in range(n_chunks):
-            with TRACER.span("pipeline.chunk", cat="pipeline"):
-                outs = self._chunk_apply(xc[c])     # each [chunk, block, ...]
-                pieces.append(tuple(
-                    o.reshape(chunk_blocks * block, *o.shape[2:])
-                    for o in outs))
-        for i in range(n_chunks * chunk_blocks, nb):
-            with TRACER.span("pipeline.block", cat="pipeline"):
-                pieces.append(
-                    self._block_apply(coords[i * block:(i + 1) * block]))
+        start = 0
+        for size in binary_parts(nb):
+            with TRACER.span("pipeline.chunk", cat="pipeline", blocks=size):
+                part = xb if size == nb else xb[start:start + size]
+                pieces.append(self._chunk_apply(part))  # each [size, block, ...]
+                _DISPATCHES.inc()
+            start += size
 
         with TRACER.span("pipeline.stitch", cat="pipeline"):
-            streamed = iter(jnp.concatenate(col)[:n] if len(col) > 1
-                            else col[0][:n] for col in zip(*pieces))
+            def rows(col):
+                o = jnp.concatenate(col) if len(col) > 1 else col[0]
+                o = o.reshape(nb * block, *o.shape[2:])
+                return o[:n] if pad else o
+            streamed = iter(rows(col) for col in zip(*pieces))
             return tuple(self._resident_output(o, n)
                          if o in self.plan.resident
                          else next(streamed) for o in self.graph.outputs)
@@ -448,6 +450,9 @@ _STATS = MetricsView({
     "store_puts": _obs_counter("compile_store_puts",
                                "artifacts persisted to a store"),
 })
+_DISPATCHES = _obs_counter("pipeline_dispatches",
+                           "jitted block-pipeline dispatches made by "
+                           "apply_batched")
 
 
 def _fn_key(fn):

@@ -247,8 +247,10 @@ def _inside(inner, outer) -> bool:
 
 def test_serve_spans_reach_the_profiler(small_inr, tmp_path):
     """With the in-memory record off, a profiler session still gets every
-    engine and pipeline span: one ``pipeline.chunk`` per full chunk, each
-    inside ``serve.dispatch``, and ``serve.wait`` after the enqueue."""
+    engine and pipeline span: one ``pipeline.chunk`` per binary part of
+    the request's block count (75 rows, 10 blocks = 8 + 2: two), each
+    inside ``serve.dispatch``, no ``pipeline.block``, and ``serve.wait``
+    after the enqueue."""
     cfg, f, x = small_inr
     cg = P.compile_gradient(f, 1, x, config=HW)
     eng = ServingEngine()
@@ -262,12 +264,11 @@ def test_serve_spans_reach_the_profiler(small_inr, tmp_path):
     assert {"serve.group", "serve.pad", "serve.dispatch", "serve.wait",
             "serve.unpad", "pipeline.pad", "pipeline.chunk",
             "pipeline.stitch"} <= set(names)
-    block, chunk = cg.config.block, cg.config.block * cg.config.chunk_blocks
-    n_blocks = -(-75 // block)
+    n_blocks = -(-75 // cg.config.block)
+    assert n_blocks == 10
     chunks = [s for s in spans if s[0] == "pipeline.chunk"]
-    assert len(chunks) == 75 // chunk >= 1
-    assert names.count("pipeline.block") == n_blocks - len(chunks) \
-        * cg.config.chunk_blocks
+    assert len(chunks) == len(P.binary_parts(n_blocks)) == 2
+    assert "pipeline.block" not in names
     (dispatch,) = [s for s in spans if s[0] == "serve.dispatch"]
     (wait,) = [s for s in spans if s[0] == "serve.wait"]
     assert all(_inside(c, dispatch) for c in chunks)

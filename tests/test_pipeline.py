@@ -111,8 +111,10 @@ def test_apply_batched_matches_reference_on_unpadded_rows(small_siren, order):
 def test_apply_batched_chunked_path(small_siren):
     """Batches large enough to hit the chunked lax.map path agree with the
     per-block path and the reference.  The chunk size is part of the
-    artifact's HardwareConfig (not a per-call kwarg), so each artifact
-    compiles exactly two traces regardless of the batch sizes served."""
+    artifact's HardwareConfig (not a per-call kwarg); ``apply_batched``
+    splits a request into the binary parts of its block count, so each
+    artifact compiles at most ``floor(log2(nb_max)) + 1`` map lengths
+    regardless of the batch sizes served."""
     from repro.core.config import HardwareConfig
 
     cfg, f, x = small_siren
@@ -123,13 +125,64 @@ def test_apply_batched_chunked_path(small_siren):
     assert cg_chunked is not cg_blocks, "distinct configs, distinct artifacts"
     q = jax.random.uniform(jax.random.PRNGKey(7),
                            (70, cfg.in_features), jnp.float32, -1, 1)
-    got_chunked = cg_chunked.apply_batched(q)   # 4 chunks + 1 block
-    got_blocks = cg_blocks.apply_batched(q)     # blocks only
+    got_chunked = cg_chunked.apply_batched(q)   # 9 blocks = 8 + 1
+    got_blocks = cg_blocks.apply_batched(q)
     for a, b in zip(got_chunked, got_blocks):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
     gfn = paper_gradients(f, 1, cfg.out_features, cfg.in_features)
     for a, b in zip(gfn(q), got_chunked):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+def test_binary_parts():
+    assert P.binary_parts(1) == [1]
+    assert P.binary_parts(10) == [8, 2]
+    assert P.binary_parts(10240) == [8192, 2048]
+    assert P.binary_parts(2048) == [2048]
+    assert all(sum(P.binary_parts(nb)) == nb for nb in range(1, 300))
+
+
+def _dispatches() -> float:
+    from repro.obs.metrics import REGISTRY
+    return REGISTRY.get("pipeline_dispatches").value()
+
+
+@pytest.mark.parametrize("n", [1, 7, 8, 75, 8 * 13 + 3, 8 * 64,
+                               8 * 64 * 3 + 5])
+def test_apply_batched_binary_parts_match_block_loop(small_siren, n):
+    """A request dispatched as the binary parts of its block count equals
+    ``apply_block`` looped over its edge-padded blocks, bitwise (the CPU
+    backend computes the mapped and the single block alike), in
+    ``popcount(ceil(n / block))`` dispatches."""
+    cfg, f, x = small_siren
+    cg = P.compile_gradient(f, 2, x, block=8)
+    q = jax.random.uniform(jax.random.PRNGKey(n), (n, cfg.in_features),
+                           jnp.float32, -1, 1)
+    nb = -(-n // 8)
+    padded = jnp.concatenate([q, jnp.broadcast_to(q[-1:], (nb * 8 - n,
+                                                           cfg.in_features))])
+    blocks = [cg.apply_block(padded[i * 8:(i + 1) * 8]) for i in range(nb)]
+    want = [jnp.concatenate(col)[:n] for col in zip(*blocks)]
+
+    before = _dispatches()
+    got = cg.apply_batched(q)
+    assert _dispatches() - before == bin(nb).count("1")
+    assert len(got) == len(want)
+    for a, b in zip(want, got):
+        assert a.shape == b.shape
+        np.testing.assert_array_equal(a, b)
+
+
+def test_apply_batched_compiles_one_map_per_binary_part(small_siren):
+    """Every request size from 1 to 40 blocks needs only the map lengths
+    1, 2, 4, 8, 16 and 32: at most six compiled traces."""
+    cfg, f, x = small_siren
+    cg = P.compile_gradient(f, 1, x, block=8)
+    q = jax.random.uniform(jax.random.PRNGKey(3), (8 * 40, cfg.in_features),
+                           jnp.float32, -1, 1)
+    for k in range(1, 41):
+        cg.apply_batched(q[:8 * k])
+    assert cg._chunk_apply._cache_size() <= 6
 
 
 def test_artifact_carries_the_whole_pipeline(small_siren):

@@ -88,6 +88,17 @@ def test_serving_chunk_step_compiles(order, seed_siren, one_chip,
     assert "%region_fwd." in text, "the kernel's name is its instruction's"
 
 
+def test_order3_request_map_compiles(seed_siren, one_chip, compiled_kernels):
+    """``apply_batched`` serves a 128 x 128 tile at order 3 as one map
+    over its 2,048 blocks: that long map lowers for the chip, with its
+    region and standalone chain kernels."""
+    cfg, _, f = seed_siren
+    cg = P.compile_gradient(f, 3, jnp.zeros((TRACE_ROWS, 2)), config=HW)
+    request = _shape((2048, cg.config.block, cfg.in_features), one_chip)
+    text = jax.jit(cg._make_chunk_fn()).lower(request).compile().as_text()
+    assert "%region_fwd." in text and "%fused_chain." in text
+
+
 def test_fit_stream_value_and_grad_compiles(seed_siren, one_chip,
                                             compiled_kernels):
     """Order-1 streamed fit step: forward region kernel plus the
