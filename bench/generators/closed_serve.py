@@ -50,7 +50,7 @@ def _engine(run, params, x_trace):
     from repro.core import pipeline
     from repro.serve import ServingEngine
     cfg, tr = run.cell.config, run.cell.traffic
-    f = program.siren(cfg, params)
+    f = program.inr(cfg, params)
     engine = ServingEngine()
     filters = tr.get("filters")
     with run.phase("compile_s"):

@@ -260,7 +260,7 @@ def run(run, control=None):
     key = run.key("schedule")
 
     with run.phase("compile_s"):
-        cf = compile_fit(program.siren(cfg, params), GradMSE(), tr["order"],
+        cf = compile_fit(program.inr(cfg, params), GradMSE(), tr["order"],
                          pts[:tr["trace_rows"]], params=params)
 
     def job(steps):

@@ -6,6 +6,7 @@ lives in files of its own, found by name:
 
   configs/<config>.json        sizes, precision, source (``file`` in the spec)
   reference/<reference>.py     the plain reference the configuration names
+  programs/<architecture>.py   the program's network the configuration names
   traffic/<traffic>.json       parameters of the mix; ``generator`` names
                                the general generator that reads them
   generators/<generator>.py    set-up, warm-up, window and comparison
@@ -98,6 +99,14 @@ def load_cell(workload: str, root: Path) -> Cell:
     configs = {c["name"]: c for c in spec["configs"]}
     entry = configs[w["config"]]
     bench = root / spec["paths"][0]
+    config = load_json(root / entry["file"])
+    arch = config.get("architecture")
+    if arch is None:
+        raise BenchError(f"configuration {w['config']!r} names no "
+                         "architecture")
+    if not (bench / "programs" / f"{arch}.py").is_file():
+        raise BenchError(f"configuration {w['config']!r} names architecture "
+                         f"{arch!r}, and there is no programs/{arch}.py")
     e2e = [m for m in spec["end_to_end"] if _applies(m, workload)]
     reported = {m["name"] for m in e2e}
     per_layer = [m for m in spec["per_layer"]
@@ -105,7 +114,7 @@ def load_cell(workload: str, root: Path) -> Cell:
                      else m["moves"] in reported)]
     return Cell(name=workload, chips=int(w["chips"]),
                 config_name=w["config"], traffic_name=w["traffic"],
-                config=load_json(root / entry["file"]),
+                config=config,
                 traffic=load_json(bench / "traffic" / f"{w['traffic']}.json"),
                 limits=load_json(bench / "limits" / f"{workload}.json"),
                 end_to_end=e2e, per_layer=per_layer, bench=bench)

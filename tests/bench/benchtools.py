@@ -2,7 +2,12 @@
 ``bench/`` beside the program's ``src/``, with tiny cells added as files
 of their own (a configuration, traffic mixes, limits) and a
 ``BENCHMARK.json`` that names them.  Nothing of the committed benchmark is
-edited: a tiny cell differs only in the files it adds."""
+edited: a tiny cell differs only in the files it adds.
+
+Besides tiny SIREN cells, the checkout holds a second architecture, a
+tanh MLP, brought in as a new architecture comes: its configurations,
+its reference (``tanh_mlp/reference.py``) and its program hook
+(``tanh_mlp/program.py``) as files of their own."""
 
 from __future__ import annotations
 
@@ -19,10 +24,24 @@ TINY = {
     "in_features": 2, "out_features": 1, "hidden_features": 16,
     "num_hidden_layers": 3, "first_omega_0": 30.0, "hidden_omega_0": 30.0,
     "outermost_linear": True, "dtype": "float32", "precision": "highest",
-    "reference": "siren", "reduced": ["hidden_features"],
+    "architecture": "siren", "reference": "siren",
+    "reduced": ["hidden_features"],
     "flops_per_row": {"tower_o1": 1.0, "tower_o2": 1.0, "tower_o3": 1.0,
                       "grad_mse_vg_o1": 1.0},
 }
+
+# a second architecture: 2 -> 3 x 16 tanh -> 1 and 3 -> 3 x 16 tanh -> 1;
+# the counts are those of bench/flops.py, which a test recomputes
+TANH = {
+    "name": "tanh-tiny", "source": "https://arxiv.org/abs/1711.10561",
+    "in_features": 2, "out_features": 1, "hidden_features": 16,
+    "hidden_layers": 3, "dtype": "float32", "precision": "highest",
+    "architecture": "tanh_mlp", "reference": "tanh_mlp", "reduced": [],
+    "flops_per_row": {"tower_o2": 8177.0, "grad_mse_vg_o1": 7521.75},
+}
+TANH3 = dict(TANH, name="tanh-tiny-3d", in_features=3,
+             flops_per_row={"grad_mse_vg_o1": 7687.0})
+ARCHITECTURES = {"tanh_mlp": REPO / "tests" / "bench" / "tanh_mlp"}
 
 # tiny traffic: same generators and keys as the committed mixes, small sizes
 TRAFFIC = {
@@ -48,14 +67,20 @@ TRAFFIC = {
                  "work": "grad_mse_vg_o1", "row_cols": 6,
                  "weight_passes": 2},
 }
+TRAFFIC["tiny-tanh-o2"] = dict(TRAFFIC["tiny-o3"], order=2, work="tower_o2",
+                               row_cols=7)
+TRAFFIC["tiny-tanh-fit"] = dict(TRAFFIC["tiny-fit"])
 
 CELLS = {"tiny-bank": ("siren-tiny", "tiny-bank"),
          "tiny-o3": ("siren-tiny", "tiny-o3"),
-         "tiny-fit": ("siren-tiny-3d", "tiny-fit")}
+         "tiny-fit": ("siren-tiny-3d", "tiny-fit"),
+         "tiny-tanh-o2": ("tanh-tiny", "tiny-tanh-o2"),
+         "tiny-tanh-fit": ("tanh-tiny-3d", "tiny-tanh-fit")}
 
 # each tiny cell is held to the limits of the committed cell it stands for
 LIMITS_OF = {"tiny-bank": "image-edit-bank", "tiny-o3": "image-grad-o3",
-             "tiny-fit": "sdf-fit-normals"}
+             "tiny-fit": "sdf-fit-normals", "tiny-tanh-o2": "image-edit-bank",
+             "tiny-tanh-fit": "sdf-fit-normals"}
 
 CPU_PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
              "hbm_bytes": 1e10}
@@ -74,8 +99,13 @@ def make_checkout(tmp: Path) -> Path:
                     ignore=shutil.ignore_patterns("__pycache__"))
     (root / "src").symlink_to(REPO / "src")
     spec = json.loads((REPO / "BENCHMARK.json").read_text())
+    for arch, src in ARCHITECTURES.items():
+        shutil.copy(src / "reference.py",
+                    root / "bench" / "reference" / f"{arch}.py")
+        shutil.copy(src / "program.py",
+                    root / "bench" / "programs" / f"{arch}.py")
     tiny3 = dict(TINY, name="siren-tiny-3d", in_features=3)
-    for cfg in (TINY, tiny3):
+    for cfg in (TINY, tiny3, TANH, TANH3):
         _dump(root / "bench" / "configs" / f"{cfg['name']}.json", cfg)
         spec["configs"].append({"name": cfg["name"], "source": cfg["source"],
                                 "file": f"bench/configs/{cfg['name']}.json",

@@ -15,6 +15,8 @@ import pytest
 import benchtools as bt
 
 SERVING = ["tiny-bank", "tiny-o3"]
+# the second architecture's cells (benchtools: a tanh MLP, files only)
+TANH = ["tiny-tanh-o2", "tiny-tanh-fit"]
 
 
 @pytest.fixture(scope="module")
@@ -22,7 +24,7 @@ def root(tmp_path_factory):
     return bt.make_checkout(tmp_path_factory.mktemp("bench"))
 
 
-@pytest.mark.parametrize("cell", SERVING + ["tiny-fit"])
+@pytest.mark.parametrize("cell", SERVING + ["tiny-fit"] + TANH)
 def test_cell_runs_correct(root, cell):
     line = bt.rehearse(root, cell)
     assert line["correct"], line["checks"]
@@ -54,7 +56,7 @@ def _alter_answers(monkeypatch):
     monkeypatch.setattr(pipeline.CompiledGradient, "__init__", patched)
 
 
-@pytest.mark.parametrize("cell", SERVING)
+@pytest.mark.parametrize("cell", SERVING + ["tiny-tanh-o2"])
 def test_altered_answer_is_not_correct(root, cell, monkeypatch):
     _alter_answers(monkeypatch)
     line = bt.rehearse(root, cell)

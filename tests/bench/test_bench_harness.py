@@ -15,6 +15,9 @@ import sys
 from pathlib import Path
 
 
+import numpy as np
+import pytest
+
 import benchtools as bt
 
 REPO = bt.REPO
@@ -50,6 +53,64 @@ def test_bare_benchmark_directory_is_refused(tmp_path):
     assert "{" not in r.stdout
 
 
+@pytest.mark.parametrize("architecture", [None, "no-such-network"])
+def test_configuration_without_its_program_is_refused(tmp_path,
+                                                      architecture):
+    """A configuration that names no architecture, or one with no
+    ``programs/`` file, is refused before set-up: no result line."""
+    root = bt.make_checkout(tmp_path)
+    path = root / "bench" / "configs" / "siren-tiny.json"
+    cfg = json.loads(path.read_text())
+    cfg.pop("architecture")
+    if architecture:
+        cfg["architecture"] = architecture
+    path.write_text(json.dumps(cfg))
+    r = _run(root, "--workload", "tiny-o3", "--seed", "4", "--seconds", "1",
+             "--trace", "0")
+    assert r.returncode != 0
+    assert "architecture" in r.stderr and "needs a TPU" not in r.stderr
+    assert "{" not in r.stdout
+
+
+def _program():
+    sys.path.insert(0, str(REPO / "bench"))
+    import harness
+    import program
+    return harness, program
+
+
+@pytest.mark.parametrize("config", [c["file"] for c in SPEC["configs"]])
+def test_program_inr_is_the_siren_function(config):
+    """On a committed configuration the hook gives, bit for bit, the
+    program's ``siren_fn`` under the configuration's ``SirenConfig``."""
+    import jax
+    from repro.configs.siren import SirenConfig
+    from repro.inr.siren import siren_fn
+    harness, program = _program()
+    cfg = json.loads((REPO / config).read_text())
+    ref = harness.load_module(REPO / "bench" / "reference" / "siren.py",
+                              "hook_reference")
+    params = ref.init_params(cfg, jax.random.PRNGKey(5))
+    x = jax.random.uniform(jax.random.PRNGKey(6), (64, cfg["in_features"]),
+                           minval=-1.0, maxval=1.0)
+    want = siren_fn(SirenConfig(
+        in_features=cfg["in_features"], out_features=cfg["out_features"],
+        hidden_features=cfg["hidden_features"],
+        hidden_layers=cfg["num_hidden_layers"] + 1,
+        w0=cfg["hidden_omega_0"]), params)(x)
+    np.testing.assert_array_equal(program.inr(cfg, params)(x), want)
+
+
+@pytest.mark.parametrize("architecture", ["siren", "tanh_mlp"])
+@pytest.mark.parametrize("change", [{"precision": "high"},
+                                    {"dtype": "bfloat16"}])
+def test_program_inr_refuses_other_precision(architecture, change):
+    _, program = _program()
+    cfg = dict(bt.TINY if architecture == "siren" else bt.TANH, **change)
+    with pytest.raises(ValueError, match="float32 at highest"):
+        program.inr(cfg, params=None)
+
+
 def test_spec_keeps_to_its_contract():
     assert set(SPEC) == {"command", "paths", "run_seconds", "configs",
                          "workloads", "end_to_end", "per_layer"}
@@ -60,17 +121,19 @@ def test_spec_keeps_to_its_contract():
     assert all(NAME.match(n) for n in names)
     assert len(set(names)) == len(names)
     configs = {c["name"]: c for c in SPEC["configs"]}
+    bench = REPO / SPEC["paths"][0]
     for c in SPEC["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         cfg = json.loads((REPO / c["file"]).read_text())
         assert cfg["reduced"] == c["reduced"]
+        assert (bench / "programs" / f"{cfg['architecture']}.py").is_file()
+        assert (bench / "reference" / f"{cfg['reference']}.py").is_file()
     e2e = {m["name"]: m for m in SPEC["end_to_end"]}
     assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.25
     for m in SPEC["end_to_end"]:
         assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
         assert 0.01 <= m["bound"] <= 0.25
         assert m["source"] in ("host_clock", "device_trace")
-    bench = REPO / SPEC["paths"][0]
     for w in SPEC["workloads"]:
         assert w["config"] in configs and w["chips"] in (1, 4)
         assert len(w["why"]) <= 200
