@@ -43,7 +43,14 @@ _OP_EXPR = {
     "Sigmoid": "jax.nn.sigmoid({0})",
     "Maximum": "jnp.maximum({0}, {1})",
     "Minimum": "jnp.minimum({0}, {1})",
-    "Select": "jnp.where({0}, {1}, {2})",
+    "Select": "jnp.where({0}, {2}, {1})",    # select_n(pred, false, true)
+    "Log1p": "jnp.log1p({0})",
+    "Eq": "{0} == {1}",
+    "Ne": "{0} != {1}",
+    "Lt": "{0} < {1}",
+    "Le": "{0} <= {1}",
+    "Gt": "{0} > {1}",
+    "Ge": "{0} >= {1}",
     "Identity": "{0}",
     "Pow": "{0} ** {1}",
 }

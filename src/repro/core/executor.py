@@ -26,6 +26,10 @@ from repro.core.segment import (SegmentPlan, build_segment_plan,
                                 classify_residents, _p)
 
 
+_COMPARE = {"Eq": jnp.equal, "Ne": jnp.not_equal, "Lt": jnp.less,
+            "Le": jnp.less_equal, "Gt": jnp.greater, "Ge": jnp.greater_equal}
+
+
 def _eval_node(node: Node, args, block_b: int | None = None):
     """Evaluate one IR node given operand values."""
     op = node.op
@@ -79,8 +83,15 @@ def _eval_node(node: Node, args, block_b: int | None = None):
         return jnp.maximum(args[0], args[1])
     if op == "Minimum":
         return jnp.minimum(args[0], args[1])
+    if op == "Log1p":
+        return jnp.log1p(args[0])
+    if op in _COMPARE:
+        return _COMPARE[op](args[0], args[1])
     if op == "Select":
-        return jnp.where(args[0], args[1], args[2])
+        # select_n(pred, *cases): a boolean pred picks cases[1] where true
+        if len(args) == 3 and jnp.result_type(args[0]) == jnp.bool_:
+            return jnp.where(args[0], args[2], args[1])
+        return jax.lax.select_n(*args)
     if op == "Convert":
         return args[0].astype(node.dtype)
     if op == "Identity":
@@ -227,7 +238,8 @@ def _run_segment(plan: SegmentPlan, seg, kernel: str, env, res_env,
             a = val(e)
             extras.append(a if a.shape == x.shape
                           else jnp.broadcast_to(a, x.shape))
-        return fused_chain(x, spec.steps, tuple(extras), block_rows=bm)
+        return fused_chain(x, spec.steps, tuple(extras), block_rows=bm,
+                           out_dtype=g.nodes[seg.output].dtype)
 
     # reference fallback: interpret the segment node-by-node
     local: dict[int, jax.Array] = {}

@@ -37,7 +37,9 @@ class Node:
         """Structural hash key under an id-canonicalization map."""
         if self.op == "Const":
             h = hashlib.sha1(np.ascontiguousarray(self.const).tobytes()).hexdigest()
-            return ("Const", self.shape, self.dtype, h)
+            # ``params`` names a traced weight's source array (core.trace):
+            # equal values from distinct weights are distinct parameters
+            return ("Const", self.shape, self.dtype, h, self.params)
         if self.op == "Input":
             return ("Input", self.params, self.shape, self.dtype)
         return (self.op, self.params, self.shape, self.dtype,

@@ -401,6 +401,13 @@ def compile_from_graph(g: ComputeGraph, *,
         dispatch = region_dispatch_table(plan, region_plan)
     else:
         dispatch = dispatch_table(plan)
+    # a dispatch entry of a segment that no kernel runs covers its nodes
+    by_id = {s.id: s for s in plan.segments}
+    _INTERP_NODES.inc(sum(len(by_id[i].nodes) for i, kind, kernel in dispatch
+                          if kernel == INTERPRET), path="serve")
+    for reason, n in (region_plan.cuts if region_plan is not None
+                      else {}).items():
+        _REGION_CUTS.inc(n, reason=reason)
 
     # precompute residents once: the paper's on-chip tensors, never re-derived
     residents: dict[int, jax.Array] = {}
@@ -453,6 +460,17 @@ _STATS = MetricsView({
 _DISPATCHES = _obs_counter("pipeline_dispatches",
                            "jitted block-pipeline dispatches made by "
                            "apply_batched")
+# what the compiler left outside the kernels, counted per compiled graph
+_INTERP_NODES = _obs_counter(
+    "compile_interp_nodes",
+    "graph nodes a compiled artifact runs on the per-node interpreter, "
+    "outside every Pallas kernel (path=serve: the block pipeline; "
+    "path=fit: the streamed fit's walk)")
+_REGION_CUTS = _obs_counter(
+    "compile_region_cuts",
+    "region boundaries the scheduler made, by reason: vmem (joining would "
+    "pass the budget), op (a segment with no region step, or operands one "
+    "kernel cannot share), forced (a config cut point)")
 
 
 def _fn_key(fn):

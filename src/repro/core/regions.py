@@ -62,12 +62,14 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from repro.core.config import HardwareConfig
-from repro.core.segment import (BUFFERING, FUSED_MM_ACT, MATMUL, STREAM_CHAIN,
-                                Segment, SegmentPlan, _p, segment_dispatch)
+from repro.core.segment import (BUFFERING, FUSED_MM_ACT, INTERPRET, MATMUL,
+                                STREAM_CHAIN, Segment, SegmentPlan, _p,
+                                segment_dispatch)
 
 CHAIN = "chain"
 MM = "mm"
 CONCAT = "concat"
+SLICE = "slice"
 
 FUSED_REGION = "FusedRegion"
 REGION_KERNEL = "region"
@@ -94,15 +96,25 @@ def _lower_segment(plan: SegmentPlan, seg: Segment):
         return (MM, seg.output, mm.inputs[0], mm.inputs[1],
                 seg.meta["bias"], seg.meta["w0"], seg.meta["apply_sin"])
     if seg.kind == BUFFERING and len(seg.nodes) == 1:
-        # a last-axis Concat of streamed 2-D tensors is row-wise — it
-        # streams like an elementwise step (the filter bank's feature
-        # assembly), so it need not cut the region
+        # a last-axis Concat or column Slice of streamed 2-D tensors is
+        # row-wise — it streams like an elementwise step (the filter bank's
+        # feature assembly; a skip connection and its transpose), so it
+        # need not cut the region
         n = g.nodes[seg.nodes[0]]
         if (n.op == "Concat" and len(n.shape) == 2
                 and _p(n, "dimension") in (1, -1)
-                and all(i not in plan.resident
-                        and len(g.nodes[i].shape) == 2 for i in n.inputs)):
+                and all(len(g.nodes[i].shape) == 2
+                        and (i not in plan.resident or _is_row_extra(plan, i))
+                        for i in n.inputs)):
             return (CONCAT, seg.output, tuple(n.inputs))
+        if n.op == "Slice" and len(n.shape) == 2:
+            x = g.nodes[n.inputs[0]]
+            start, limit = _p(n, "start_indices"), _p(n, "limit_indices")
+            strides = _p(n, "strides")
+            if (n.inputs[0] not in plan.resident and len(x.shape) == 2
+                    and start[0] == 0 and limit[0] == x.shape[0]
+                    and (strides is None or tuple(strides) == (1, 1))):
+                return (SLICE, seg.output, n.inputs[0], start[1], limit[1])
     return None
 
 
@@ -125,13 +137,17 @@ class FusedRegion:
     ``resident_inputs``  — whole-tensor VMEM operands (weights, biases).
     ``outputs``          — tensors leaving the region (consumed by another
                            region or graph outputs), written to HBM once.
-    ``spec``             — the lowered ``RegionKernelSpec`` for fused
-                           (multi-segment) regions; None for singletons,
-                           which dispatch through the classic per-segment
-                           path.
-    ``meta``             — ``vmem_bytes`` (the packing estimate), and
+    ``spec``             — the lowered ``RegionKernelSpec``; None when a
+                           segment has no region step (it runs on the
+                           per-node interpreter) or alone overflows the
+                           budget.
+    ``meta``             — ``vmem_bytes`` (the packing estimate),
                            ``col_tiles`` (max column tiles over the spec's
-                           tile groups; 1 = untiled).
+                           tile groups; 1 = untiled), and ``classic``: for a
+                           one-segment region whose segment has a standalone
+                           Pallas kernel, that kernel's name — serving
+                           dispatches it so, the fit (which differentiates
+                           only the region kernel) through the region.
     """
     id: int
     segments: tuple[int, ...]
@@ -145,7 +161,8 @@ class FusedRegion:
 
     @property
     def fused(self) -> bool:
-        return len(self.segments) > 1 and self.spec is not None
+        """Serving dispatches this region as one region megakernel."""
+        return self.spec is not None and not self.meta.get("classic")
 
     @property
     def col_tiles(self) -> int:
@@ -169,6 +186,10 @@ class RegionPlan:
     regions: list[FusedRegion]
     region_of: dict[int, int]          # segment id -> region id
     config: HardwareConfig
+    # the boundaries the scheduler made, by reason (a segment that reads
+    # nothing of the region before it is no cut): {"vmem" | "op" |
+    # "forced": count}
+    cuts: dict = field(default_factory=dict)
 
     def fused_regions(self) -> list[FusedRegion]:
         return [r for r in self.regions if r.fused]
@@ -178,6 +199,14 @@ class RegionPlan:
         fused regions, ``("seg", Segment)`` for singletons — the ONE
         schedule walk executor, codegen, and dataflow all share."""
         return [("region", r) if r.fused
+                else ("seg", self.plan.segments[r.segments[0]])
+                for r in self.regions]
+
+    def fit_units(self) -> list[tuple[str, object]]:
+        """The fit's walk: every region with a spec runs as the region
+        kernel (its backward is the one kernel the fit differentiates),
+        one-segment regions with a standalone kernel included."""
+        return [("region", r) if r.spec is not None
                 else ("seg", self.plan.segments[r.segments[0]])
                 for r in self.regions]
 
@@ -213,9 +242,8 @@ class RegionPlan:
         budget = self.config.vmem_budget
         cuts = set(self.config.region_cuts)
         for r in self.regions:
-            if not r.fused:
+            if r.spec is None:
                 continue
-            assert r.spec is not None
             assert region_vmem_bytes(plan, r, self.config) <= budget, \
                 (r.id, "region exceeds the VMEM budget")
             # a forced cut is never fused across
@@ -310,7 +338,12 @@ def _region_io(plan: SegmentPlan, members, consumers=None):
         elif step[0] == CONCAT:
             _, out, xs = step
             for i in xs:
-                want_stream(i)
+                if i in plan.resident:
+                    rows[i] = _row_cols(plan, i)   # a row-const operand
+                else:
+                    want_stream(i)
+        elif step[0] == SLICE:
+            want_stream(step[2])
         else:
             _, out, x, w, bias, _, _ = step
             want_stream(x)
@@ -336,7 +369,7 @@ def _step_operands(step):
         return (step[2],) + tuple(step[4])
     if step[0] == CONCAT:
         return tuple(step[2])
-    return (step[2],)
+    return (step[2],)                  # mm, slice
 
 
 def _node_width(g, nid: int) -> int:
@@ -372,7 +405,7 @@ def plan_col_tiles(plan: SegmentPlan, io, config: HardwareConfig) -> tuple:
             out = step[1]
             if members and step[0] == MM and step[2] in members:
                 break                          # reducer candidate
-            if step[0] == CONCAT:
+            if step[0] in (CONCAT, SLICE):
                 break                          # operand widths differ: untilable
             if _node_width(g, out) != W or out in out_set:
                 break
@@ -561,7 +594,13 @@ def build_region_plan(plan: SegmentPlan,
     """Greedily merge adjacent expressible, connected segments into
     FusedRegions under the config's VMEM budget and cut points.  With
     ``fuse_regions=False`` every segment is a singleton region (the classic
-    per-segment pipeline, byte-for-byte)."""
+    per-segment pipeline, byte-for-byte).
+
+    Every region whose segments all lower to steps and fit the budget gets
+    a spec, one-segment regions included; those whose segment has a
+    standalone Pallas kernel keep it for serving (``meta["classic"]``).
+    The plan counts its boundaries by reason (``RegionPlan.cuts``): the
+    budget, a segment with no step, a config cut point."""
     if config is None:
         config = plan.config
     if config is None:
@@ -569,9 +608,13 @@ def build_region_plan(plan: SegmentPlan,
         config = DEFAULT_CONFIG
     regions: list[FusedRegion] = []
     region_of: dict[int, int] = {}
+    cut_reasons: dict[str, int] = {}
     cuts = set(config.region_cuts)
     consumers = plan.graph.consumers()     # built once, shared by every trial
     cur: list = []                         # [(Segment, step)]
+
+    def cut(reason: str):
+        cut_reasons[reason] = cut_reasons.get(reason, 0) + 1
 
     def singleton(seg: Segment) -> FusedRegion:
         return FusedRegion(
@@ -584,12 +627,12 @@ def build_region_plan(plan: SegmentPlan,
         nonlocal cur
         if not cur:
             return
-        if len(cur) == 1:
-            r = singleton(cur[0][0])
+        io = _region_io(plan, cur, consumers)
+        est, tiles = _pack_region(plan, io, config) if io else (0, ())
+        if io is None or est > config.vmem_budget:
+            r = singleton(cur[0][0])           # only ever one segment here
         else:
-            io = _region_io(plan, cur, consumers)
             stream_in, rows, bcast, res_in, outputs, steps = io
-            est, tiles = _pack_region(plan, io, config)
             from repro.kernels.region import RegionKernelSpec
             spec = RegionKernelSpec(
                 steps=steps,
@@ -598,12 +641,16 @@ def build_region_plan(plan: SegmentPlan,
                 bcast_rows=tuple(n for n, _ in rows),
                 tile_groups=tiles)
             col_tiles = max((t.n_tiles for t in tiles), default=1)
+            meta = {"vmem_bytes": est, "col_tiles": col_tiles}
+            if len(cur) == 1:
+                kernel = segment_dispatch(plan, cur[0][0])
+                if kernel != INTERPRET:
+                    meta["classic"] = kernel
             r = FusedRegion(
                 id=len(regions), segments=tuple(s.id for s, _ in cur),
                 stream_inputs=stream_in, broadcast_inputs=bcast,
                 resident_inputs=res_in, outputs=outputs,
-                bcast_rows=rows, spec=spec,
-                meta={"vmem_bytes": est, "col_tiles": col_tiles})
+                bcast_rows=rows, spec=spec, meta=meta)
         for sid in r.segments:
             region_of[sid] = r.id
         regions.append(r)
@@ -612,6 +659,8 @@ def build_region_plan(plan: SegmentPlan,
     for seg in plan.segments:
         step = _lower_segment(plan, seg) if config.fuse_regions else None
         if step is None:
+            if config.fuse_regions:
+                cut("op")
             flush()
             r = singleton(seg)
             region_of[seg.id] = r.id
@@ -619,20 +668,26 @@ def build_region_plan(plan: SegmentPlan,
             continue
         if cur:
             produced = {s.output for s, _ in cur}
+            connected = any(i in produced for i in seg.stream_inputs)
             trial = cur + [(seg, step)]
             io = _region_io(plan, trial, consumers)
-            joinable = (cur[-1][0].id not in cuts
-                        and any(i in produced for i in seg.stream_inputs)
-                        and io is not None
-                        and _pack_region(plan, io, config)[0]
-                        <= config.vmem_budget)
-            if not joinable:
+            if cur[-1][0].id in cuts:
+                cut("forced")
+                flush()
+            elif connected and io is None:
+                cut("op")                  # one extra, two block shapes
+                flush()
+            elif connected and (_pack_region(plan, io, config)[0]
+                                > config.vmem_budget):
+                cut("vmem")
+                flush()
+            elif not connected:
                 flush()
         cur.append((seg, step))
     flush()
 
     rplan = RegionPlan(plan=plan, regions=regions, region_of=region_of,
-                       config=config)
+                       config=config, cuts=cut_reasons)
     rplan.validate()
     return rplan
 

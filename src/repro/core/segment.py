@@ -50,6 +50,7 @@ STREAMING_OPS = {
     "Sin", "Cos", "Mul", "Add", "Sub", "Div", "Neg", "Exp", "Log", "Tanh",
     "Pow", "IntPow", "Convert", "Select", "Maximum", "Minimum", "Identity",
     "Rsqrt", "Sqrt", "Abs", "Sign", "Sigmoid", "Erf", "Broadcast",
+    "Log1p", "Eq", "Ne", "Lt", "Le", "Gt", "Ge",
 }
 # ops that must buffer their whole input before producing output
 BUFFERING_OPS = {"T", "Permute", "Reshape", "Sum", "Max", "Concat", "Slice",
@@ -91,7 +92,8 @@ def row_const_residents(g: ComputeGraph, resident: set[int]) -> set[int]:
     elementwise = {"Sin", "Cos", "Mul", "Add", "Sub", "Div", "Neg", "Exp",
                    "Log", "Tanh", "Rsqrt", "Sqrt", "Abs", "Sign", "Sigmoid",
                    "Erf", "IntPow", "Pow", "Maximum", "Minimum", "Select",
-                   "Convert", "Identity"}
+                   "Convert", "Identity", "Log1p", "Eq", "Ne", "Lt", "Le",
+                   "Gt", "Ge"}
 
     def arg_ok(i, out_rank):
         """Operand is row-const, or broadcasts without touching axis 0."""

@@ -59,6 +59,15 @@ PRIM_MAP = {
     "sign": "Sign",
     "logistic": "Sigmoid",
     "erf": "Erf",
+    "log1p": "Log1p",
+    # comparisons: boolean masks, consumed by Select (the derivative rules
+    # of max, abs and logaddexp, and the isnan / isinf tests of softplus)
+    "eq": "Eq",
+    "ne": "Ne",
+    "lt": "Lt",
+    "le": "Le",
+    "gt": "Gt",
+    "ge": "Ge",
 }
 
 _CALL_PRIMS = {"pjit", "closed_call", "core_call", "xla_call", "remat", "checkpoint",
@@ -181,6 +190,24 @@ def extract_graph(fn, *example_args, flatten_outputs=True) -> ComputeGraph:
                     consts_env[ov] = nid
                     continue
 
+            # --- split (the transpose of concatenate) becomes one Slice per
+            # output, so the passes, the executor and the kernels see the
+            # op they already know
+            if prim == "split":
+                axis = eqn.params["axis"]
+                shape = eqn.invars[0].aval.shape
+                lo = 0
+                for ov, size in zip(eqn.outvars, eqn.params["sizes"]):
+                    start = [0] * len(shape)
+                    limit = list(shape)
+                    start[axis], limit[axis] = lo, lo + size
+                    lo += size
+                    consts_env[ov] = g.add(
+                        "Slice", ov.aval.shape, ov.aval.dtype, in_ids,
+                        (("start_indices", tuple(start)),
+                         ("limit_indices", tuple(limit)), ("strides", None)))
+                continue
+
             if op is None:
                 op = prim[:1].upper() + prim[1:]     # passthrough with raw name
             if len(eqn.outvars) == 1:
@@ -198,8 +225,12 @@ def extract_graph(fn, *example_args, flatten_outputs=True) -> ComputeGraph:
     for i, v in enumerate(closed.jaxpr.invars):
         top_env[v] = g.add("Input", v.aval.shape, v.aval.dtype, params=(("idx", i),))
     for cv, cval in zip(closed.jaxpr.constvars, closed.consts):
+        # a closed-over array (a weight) keeps its source's identity: two
+        # weights that happen to hold equal values (the zero biases of a
+        # freshly initialised net) stay two Consts, one per parameter
         arr = np.asarray(cval)
-        top_env[cv] = g.add("Const", arr.shape, arr.dtype, const=arr)
+        top_env[cv] = g.add("Const", arr.shape, arr.dtype,
+                            params=(("src", id(cval)),), const=arr)
     walk(closed.jaxpr, top_env)
     g.outputs = [read(v, top_env) for v in closed.jaxpr.outvars]
     g.prune_dead()

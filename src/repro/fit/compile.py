@@ -74,6 +74,12 @@ def match_trainable(cg, params):
         matches = [i for i, a in enumerate(arrs)
                    if a.shape == c.shape and a.dtype == c.dtype
                    and np.array_equal(a, c)]
+        # equal leaves (zero biases) are told apart by the array each
+        # Const was traced from, when the template is fn's own params
+        src = dict(n.params).get("src")
+        own = [i for i in matches if id(leaves[i]) == src]
+        if own:
+            matches = own
         if not matches:
             fixed[nid] = jnp.asarray(c)
         elif len(matches) == 1:
@@ -198,9 +204,11 @@ def _make_fit_block_fn(cg, checkpoints):
 
 
 def _fit_units(cg):
-    """The execution-unit walk the fit pipeline shares with serving."""
+    """The execution-unit walk of the fit: under Pallas every region with
+    a spec runs through the differentiable region kernel
+    (``RegionPlan.fit_units``); the rest on the per-node interpreter."""
     if cg.region_plan is not None and cg.config.use_pallas:
-        return cg.region_plan.units()
+        return cg.region_plan.fit_units()
     return [("seg", s) for s in cg.plan.segments]
 
 
@@ -425,5 +433,7 @@ def compile_fit(fn, loss: Objective, order: int, example_coords, *,
     leaf_of, fixed, treedef, leaves = match_trainable(cg, params)
     cf = CompiledFit(cg=cg, loss=loss, checkpoints=cuts, leaf_of=leaf_of,
                      fixed=fixed, treedef=treedef, template_leaves=leaves)
+    pipeline._INTERP_NODES.inc(sum(len(u.nodes) for kind, u in _fit_units(cg)
+                                   if kind == "seg"), path="fit")
     pipeline._FIT_CACHE[key] = cf
     return cf

@@ -84,3 +84,35 @@ class LaplacianMSE(Objective):
 
     def target_cols(self, C: int, D: int) -> int:
         return C
+
+
+@dataclass(frozen=True)
+class IGRLoss(Objective):
+    """IGR's surface reconstruction loss (Gropp et al., ICML 2020), per
+    row, over one channel, with IGR's published weights (normals 1,
+    eikonal 0.1).  Target layout is ``[N, D]``: the unit normal ``n`` of a
+    surface row, the zero vector on an eikonal-only row, so ``s = |n|^2``
+    marks the surface rows:
+
+        s a (|y| + |dy/dx - n|) + (1 - s) e 0.1 (|dy/dx| - 1)^2.
+
+    ``surface_weight`` (a) and ``eikonal_weight`` (e) are the step's rows
+    over the surface and eikonal rows a step holds, so that the step's
+    mean row loss is IGR's sum of per-term means."""
+    min_order: int = 1
+    surface_weight: float = 1.0
+    eikonal_weight: float = 1.0
+
+    def row_loss(self, outs, target, C: int, D: int):
+        if C != 1:
+            raise ValueError("IGRLoss is written for one output channel")
+        y, dy = outs[0][:, 0], outs[1]
+        s = jnp.sum(target * target, axis=-1)
+        surface = (jnp.abs(y)
+                   + jnp.sqrt(jnp.sum((dy - target) ** 2, axis=-1)))
+        eikonal = (jnp.sqrt(jnp.sum(dy * dy, axis=-1)) - 1.0) ** 2
+        return (s * self.surface_weight * surface
+                + (1.0 - s) * self.eikonal_weight * 0.1 * eikonal)
+
+    def target_cols(self, C: int, D: int) -> int:
+        return C * D

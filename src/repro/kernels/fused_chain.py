@@ -10,6 +10,13 @@ The chain is a static list of (op, operand) tuples evaluated inside the
 kernel body at trace time:
     [("sin", None), ("scale", 30.0), ("add_row", bias), ("mul", other)]
 `add_row`/`mul` take a second streamed input of matching block shape.
+
+Comparisons (``eq`` ``ne`` ``lt`` ``le`` ``gt`` ``ge``) take the same second
+input, or a scalar baked in as their operand, and leave a boolean mask in
+the chain; ``("select", slot)`` is ``select_n(pred, false, true)`` with the
+chain value at ``slot`` of those three and the other two as inputs.  A
+mask made and consumed inside one kernel stays a VMEM value; one that
+crosses a kernel boundary travels as float32 0 / 1 (``as_stream``).
 """
 
 from __future__ import annotations
@@ -29,18 +36,50 @@ UNARY = {
     "sin": jnp.sin, "cos": jnp.cos, "exp": jnp.exp, "tanh": jnp.tanh,
     "neg": lambda x: -x, "abs": jnp.abs, "relu": lambda x: jnp.maximum(x, 0),
     "sigmoid": jax.nn.sigmoid, "silu": jax.nn.silu, "square": jnp.square,
+    "log1p": jnp.log1p,
 }
 BINARY = {"mul", "add", "sub", "div", "max", "min"}
+# ``ne`` is the unordered compare, true where either side is NaN, as XLA's
+# ``ne``: Mosaic lowers ``!=`` as an ordered compare, so it is written as
+# not-equal, which keeps ``isnan(x) = x != x`` a NaN test inside a kernel
+COMPARE = {
+    "eq": lambda a, b: a == b,
+    "ne": lambda a, b: jnp.logical_not(a == b),
+    "lt": lambda a, b: a < b, "le": lambda a, b: a <= b,
+    "gt": lambda a, b: a > b, "ge": lambda a, b: a >= b,
+}
+SELECT = "select"
+
+
+def n_extras(step) -> int:
+    """Streamed inputs one chain step consumes: one per binary step and per
+    comparison without a baked scalar, two per select."""
+    op, operand = step
+    if op in BINARY or (op in COMPARE and operand is None):
+        return 1
+    return 2 if op == SELECT else 0
+
+
+def stream_dtype(dtype):
+    """The dtype a value crosses HBM in: a boolean mask as float32 0 / 1
+    (a select reads either form as its predicate)."""
+    dtype = jnp.dtype(dtype)
+    return jnp.dtype(jnp.float32) if dtype == jnp.bool_ else dtype
+
+
+def as_stream(a):
+    """A kernel operand in its ``stream_dtype``."""
+    return a.astype(stream_dtype(a.dtype))
 
 
 def eval_chain(h, chain, extras=()):
     """Apply a static chain of (op, operand) steps to ``h`` (float32).
 
-    ``extras`` holds one float32 array per BINARY step, in step order.  This
-    is the single evaluation rule both ``fused_chain`` and the region
-    megakernel (``kernels/region.py``) trace into their bodies, so a chain
-    computes bit-identically whether it runs standalone or fused into a
-    region."""
+    ``extras`` holds the streamed inputs the steps consume, in step order
+    (``n_extras`` each).  This is the single evaluation rule both
+    ``fused_chain`` and the region megakernel (``kernels/region.py``) trace
+    into their bodies, so a chain computes bit-identically whether it runs
+    standalone or fused into a region."""
     ei = 0
     for op, operand in chain:
         if op in UNARY:
@@ -64,6 +103,19 @@ def eval_chain(h, chain, extras=()):
                 h = jnp.minimum(h, other)
             else:
                 h = h / other
+        elif op in COMPARE:
+            if operand is None:
+                other = extras[ei]
+                ei += 1
+            else:
+                other = operand
+            h = COMPARE[op](h, other)
+        elif op == SELECT:
+            cases = [extras[ei], extras[ei + 1]]
+            ei += 2
+            cases.insert(operand, h)
+            pred, lo, hi = cases
+            h = jnp.where(pred, hi, lo)
         else:
             raise ValueError(f"fused_chain: unknown op {op}")
     return h
@@ -79,10 +131,15 @@ def _chain_kernel(*refs, chain, n_extra):
 
 
 def fused_chain(x: jax.Array, chain, extras=(), *, block_rows: int = 256,
-                interpret: bool | None = None):
-    """Apply `chain` to x: [R, C] streaming block_rows rows at a time."""
+                out_dtype=None, interpret: bool | None = None):
+    """Apply `chain` to x: [R, C] streaming block_rows rows at a time.
+    ``out_dtype`` (default ``x``'s) is the chain output's: a chain that
+    ends on a comparison returns a boolean mask."""
     if interpret is None:
         interpret = interpret_default()
+    out_dtype = jnp.dtype(out_dtype or x.dtype)
+    x = as_stream(x)
+    extras = tuple(as_stream(e) for e in extras)
     R, C = x.shape
     br = min(block_rows, R)
     pad = (-R) % br
@@ -91,19 +148,18 @@ def fused_chain(x: jax.Array, chain, extras=(), *, block_rows: int = 256,
         extras = tuple(jnp.pad(e, ((0, pad), (0, 0))) for e in extras)
     Rp = R + pad
     n_extra = len(extras)
-    n_bin = sum(1 for op, _ in chain if op in BINARY)
-    assert n_bin == n_extra, (n_bin, n_extra)
+    assert sum(map(n_extras, chain)) == n_extra, (chain, n_extra)
 
     out = pl.pallas_call(
         functools.partial(_chain_kernel, chain=tuple(chain), n_extra=n_extra),
         grid=(Rp // br,),
         in_specs=[pl.BlockSpec((br, C), lambda i: (i, 0))] * (1 + n_extra),
         out_specs=pl.BlockSpec((br, C), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Rp, C), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((Rp, C), stream_dtype(out_dtype)),
         interpret=interpret,
         name="fused_chain",
     )(x, *extras)
-    return out[:R]
+    return out[:R].astype(out_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -112,10 +168,16 @@ def fused_chain(x: jax.Array, chain, extras=(), *, block_rows: int = 256,
 
 # IR op -> kernel unary name
 _IR_UNARY = {"Sin": "sin", "Cos": "cos", "Exp": "exp", "Tanh": "tanh",
-             "Neg": "neg", "Abs": "abs", "Sigmoid": "sigmoid"}
+             "Neg": "neg", "Abs": "abs", "Sigmoid": "sigmoid",
+             "Log1p": "log1p"}
 # IR op -> kernel binary name
 _IR_BINARY = {"Mul": "mul", "Add": "add", "Sub": "sub", "Div": "div",
               "Maximum": "max", "Minimum": "min"}
+# IR op -> kernel comparison, and the comparison with its sides swapped
+_IR_COMPARE = {"Eq": "eq", "Ne": "ne", "Lt": "lt", "Le": "le", "Gt": "gt",
+               "Ge": "ge"}
+_SWAPPED = {"eq": "eq", "ne": "ne", "lt": "gt", "le": "ge", "gt": "lt",
+            "ge": "le"}
 
 
 @dataclass(frozen=True)
@@ -123,7 +185,7 @@ class ChainSpec:
     """A StreamChain segment lowered to one ``fused_chain`` invocation.
 
     ``steps`` is the kernel's static ``chain`` argument; ``extras`` holds the
-    producer node id feeding each binary step's second operand, in order.
+    producer node ids of the steps' other operands, in order.
     ``x`` is the primary streamed input the chain starts from."""
     x: int
     steps: tuple
@@ -149,7 +211,10 @@ def build_chain_spec(g, node_ids, *, resident):
     Expressible ops: the _IR_UNARY map, IntPow(y=2) as square, and
     Mul/Add/Sub/Div — with a size-1 Const operand baked in as scale/offset,
     otherwise as a binary step streaming the second operand.  Sub/Div require
-    the chain value in the left slot (the kernel computes ``h op other``)."""
+    the chain value in the left slot (the kernel computes ``h op other``).
+    Comparisons take the chain value on either side (swapped to the left)
+    and bake a size-1 Const in; a boolean Select takes it in any of its
+    three slots."""
     if not node_ids:
         return None
     steps: list = []
@@ -195,6 +260,34 @@ def build_chain_spec(g, node_ids, *, resident):
                     return None             # streamed extra must match blocks
                 steps.append((_IR_BINARY[n.op], None))
                 extras.append(other)
+        elif n.op in _IR_COMPARE:
+            if len(n.inputs) != 2:
+                return None
+            slot = 0 if n.inputs[0] == primary else 1
+            op = _IR_COMPARE[n.op]
+            op = op if slot == 0 else _SWAPPED[op]
+            other = n.inputs[1 - slot]
+            v = _scalar_const(g, other)
+            if v is not None:
+                steps.append((op, v))
+            else:
+                if other not in resident and g.nodes[other].shape != n.shape:
+                    return None
+                steps.append((op, None))
+                extras.append(other)
+        elif n.op == "Select":
+            if (len(n.inputs) != 3
+                    or g.nodes[n.inputs[0]].dtype != "bool"):
+                return None                 # select_n over an integer index
+            slot = n.inputs.index(primary)
+            others = [i for k, i in enumerate(n.inputs) if k != slot]
+            if prev is not None and primary in others:
+                return None                 # the chain value twice
+            if any(o not in resident and g.nodes[o].shape != n.shape
+                   for o in others):
+                return None
+            steps.append((SELECT, slot))
+            extras.extend(others)
         else:
             return None
         if prev is None:

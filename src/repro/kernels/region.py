@@ -24,9 +24,18 @@ kernel body.
     region trades the standalone kernel's ``bk`` reduction tiling for
     never materializing the MM input/output in HBM).
   * ``("concat", out, xs)`` — a last-axis Concat of region values (the
-    gradient-feature assembly of a filter bank, DESIGN.md §9): row-wise,
-    so it streams like any elementwise step; operand widths differ, so a
-    concat step is never column-tiled.
+    gradient-feature assembly of a filter bank, DESIGN.md §9; a skip
+    connection, whose operands may include a row-constant ``bcast_rows``
+    row): row-wise, so it streams like any elementwise step; operand
+    widths differ, so a concat step is never column-tiled.
+  * ``("slice", out, x, lo, hi)`` — columns ``lo:hi`` of a region value
+    (a Split, the transpose of a skip connection's Concat, or a column
+    Slice): row-wise too, and never column-tiled.
+
+Chain steps may compare and select (``fused_chain.COMPARE``, ``SELECT``):
+their boolean masks are VMEM values of the kernel body.  A mask that
+enters or leaves the region crosses HBM as float32 0 / 1, converted at the
+call (``fused_chain.as_stream``), so no kernel operand is boolean.
 
 The grid tiles ROWS (``bm`` from the HardwareConfig): every step's row-block
 is independent, which is exactly why the paper can stream its graphs through
@@ -65,8 +74,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import interpret_default
-from repro.kernels.fused_chain import eval_chain
+from repro.kernels.common import interpret_default, vmem_params
+from repro.kernels.fused_chain import as_stream, eval_chain, stream_dtype
 
 # f32 dots at full precision: at the TPU default (one bf16 pass) every
 # SIREN layer's w0 = 30 scale leaves gradients off by several percent
@@ -75,6 +84,7 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 CHAIN = "chain"
 MM = "mm"
 CONCAT = "concat"
+SLICE = "slice"
 
 
 @dataclass(frozen=True)
@@ -129,6 +139,17 @@ class RegionKernelSpec:
     @property
     def n_stream(self) -> int:
         return len(self.stream_inputs)
+
+
+def _block_bytes(specs, shapes) -> int:
+    """Bytes of one grid step's blocks of the given specs' arrays."""
+    total = 0
+    for spec, s in zip(specs, shapes):
+        n = 1
+        for d in spec.block_shape:
+            n *= d
+        total += n * jnp.dtype(s.dtype).itemsize
+    return total
 
 
 def _eval_mm(x, w, bias, w0, apply_sin):
@@ -222,7 +243,14 @@ def _eval_steps(env, res, spec: RegionKernelSpec):
                                 w0, apply_sin)
         elif step[0] == CONCAT:
             _, out, xs = step
-            env[out] = jnp.concatenate([env[x] for x in xs], axis=-1)
+            vals = [env[x] for x in xs]
+            # a row-const operand enters as one [1, C] row
+            n = max(v.shape[0] for v in vals)
+            env[out] = jnp.concatenate(
+                [jnp.broadcast_to(v, (n, v.shape[1])) for v in vals], axis=-1)
+        elif step[0] == SLICE:
+            _, out, x, lo, hi = step
+            env[out] = env[x][:, lo:hi]
         else:
             raise ValueError(f"region: unknown step kind {step[0]!r}")
         i += 1
@@ -266,6 +294,8 @@ def region_call(spec: RegionKernelSpec, stream, rows, residents, out_info, *,
         interpret = interpret_default()
     assert len(stream) == len(spec.stream_inputs), (spec, len(stream))
     assert len(rows) == len(spec.bcast_rows), (spec, len(rows))
+    stream = [as_stream(a) for a in stream]
+    rows = [as_stream(a) for a in rows]
     residents = _as_2d(residents)
     R = stream[0].shape[0]
     br = min(bm, R)
@@ -281,8 +311,10 @@ def region_call(spec: RegionKernelSpec, stream, rows, residents, out_info, *,
     in_specs += [pl.BlockSpec(r.shape, lambda i: (0, 0)) for r in residents]
     out_specs = [pl.BlockSpec((br, c), lambda i: (i, 0))
                  for c, _ in out_info]
-    out_shape = [jax.ShapeDtypeStruct((Rp, c), dt) for c, dt in out_info]
+    out_shape = [jax.ShapeDtypeStruct((Rp, c), stream_dtype(dt))
+                 for c, dt in out_info]
 
+    operands = (*stream, *rows, *residents)
     outs = pl.pallas_call(
         functools.partial(_region_kernel, spec=spec),
         grid=(Rp // br,),
@@ -290,11 +322,13 @@ def region_call(spec: RegionKernelSpec, stream, rows, residents, out_info, *,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=vmem_params(
+            _block_bytes(in_specs + out_specs, operands + tuple(out_shape))),
         name="region_fwd",
-    )(*stream, *rows, *residents)
+    )(*operands)
     if not isinstance(outs, (list, tuple)):
         outs = (outs,)
-    return tuple(o[:R] for o in outs)
+    return tuple(o[:R].astype(dt) for o, (_, dt) in zip(outs, out_info))
 
 
 def region_call_stacked(spec: RegionKernelSpec, stream, rows, residents,
@@ -317,6 +351,8 @@ def region_call_stacked(spec: RegionKernelSpec, stream, rows, residents,
         interpret = interpret_default()
     assert len(stream) == len(spec.stream_inputs), (spec, len(stream))
     assert len(rows) == len(spec.bcast_rows), (spec, len(rows))
+    stream = [as_stream(a) for a in stream]
+    rows = [as_stream(a) for a in rows]
     K, R = stream[0].shape[0], stream[0].shape[1]
     residents = [r.reshape(K, 1, -1) if r.ndim < 3 else r for r in residents]
     br = min(bm, R)
@@ -335,8 +371,10 @@ def region_call_stacked(spec: RegionKernelSpec, stream, rows, residents,
             lambda k, i, nd=r.ndim - 1: (k,) + (0,) * nd))
     out_specs = [pl.BlockSpec((1, br, c), lambda k, i: (k, i, 0))
                  for c, _ in out_info]
-    out_shape = [jax.ShapeDtypeStruct((K, Rp, c), dt) for c, dt in out_info]
+    out_shape = [jax.ShapeDtypeStruct((K, Rp, c), stream_dtype(dt))
+                 for c, dt in out_info]
 
+    operands = (*stream, *rows, *residents)
     outs = pl.pallas_call(
         functools.partial(_region_kernel, spec=spec, stacked=True),
         grid=(K, Rp // br),
@@ -344,11 +382,13 @@ def region_call_stacked(spec: RegionKernelSpec, stream, rows, residents,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=vmem_params(
+            _block_bytes(in_specs + out_specs, operands + tuple(out_shape))),
         name="region_fwd_stacked",
-    )(*stream, *rows, *residents)
+    )(*operands)
     if not isinstance(outs, (list, tuple)):
         outs = (outs,)
-    return tuple(o[:, :R] for o in outs)
+    return tuple(o[:, :R].astype(dt) for o, (_, dt) in zip(outs, out_info))
 
 
 # --------------------------------------------------------------------------
@@ -439,6 +479,7 @@ def _region_bwd_call(spec: RegionKernelSpec, stream, rows, residents, cots, *,
         out_specs.append(pl.BlockSpec(r.shape, lambda i: (0, 0)))
         out_shape.append(jax.ShapeDtypeStruct(r.shape, jnp.float32))
 
+    operands = (*stream, *rows, *residents, *cots)
     outs = pl.pallas_call(
         functools.partial(_region_bwd_kernel, spec=spec, n_out=len(cots)),
         grid=(Rp // br,),
@@ -446,8 +487,10 @@ def _region_bwd_call(spec: RegionKernelSpec, stream, rows, residents, cots, *,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=vmem_params(
+            _block_bytes(in_specs + out_specs, operands + tuple(out_shape))),
         name="region_bwd",
-    )(*stream, *rows, *residents, *cots)
+    )(*operands)
     if not isinstance(outs, (list, tuple)):
         outs = (outs,)
     d_stream = tuple(o[:R] for o in outs[:ns])
@@ -470,11 +513,14 @@ def region_grad_fn(spec: RegionKernelSpec, out_info: tuple, bm: int = 128,
     per-tile gradient tensor and reducing it afterwards."""
     ns = len(spec.stream_inputs)
     nb = len(spec.bcast_rows)
+    # masks cross the custom_vjp as float32 0 / 1, so every operand and
+    # output it differentiates is a float (no float0 cotangents inside)
+    float_info = tuple((c, stream_dtype(dt)) for c, dt in out_info)
 
     @jax.custom_vjp
     def call(*ops):
         return region_call(spec, ops[:ns], ops[ns:ns + nb], ops[ns + nb:],
-                           out_info, bm=bm, interpret=interpret)
+                           float_info, bm=bm, interpret=interpret)
 
     def call_fwd(*ops):
         return call(*ops), ops
@@ -487,4 +533,8 @@ def region_grad_fn(spec: RegionKernelSpec, out_info: tuple, bm: int = 128,
         return tuple(d.astype(o.dtype) for d, o in zip(flat, ops))
 
     call.defvjp(call_fwd, call_bwd)
-    return call
+
+    def masked(*ops):
+        outs = call(*(as_stream(o) for o in ops))
+        return tuple(o.astype(dt) for o, (_, dt) in zip(outs, out_info))
+    return masked

@@ -140,3 +140,44 @@ def test_order3_standalone_fused_chain_compiles(seed_siren, one_chip,
                            block_rows=cg.config.bm)
     text = jax.jit(call).lower(x, *extras).compile().as_text()
     assert "tpu_custom_call" in text and "%fused_chain." in text
+
+
+def test_igr_fit_step_compiles_at_published_width(one_chip, compiled_kernels):
+    """IGR's 8 x 512 Softplus network, order-1 fit under ``IGRLoss``: the
+    compare, select and log1p steps lower for Mosaic, and the 512-wide
+    regions' backward kernels (a layer's weight, its transpose and their
+    gradient accumulators, double-buffered) get the scoped VMEM they need
+    (``kernels.common.vmem_params``) instead of overflowing 16 MiB."""
+    from repro.fit import IGRLoss
+    from repro.inr.igr import IGRConfig, igr_fn, igr_init
+    cfg = IGRConfig()
+    params = igr_init(cfg, jax.random.PRNGKey(0))
+    cf = compile_fit(igr_fn(cfg, params), IGRLoss(), 1,
+                     jnp.zeros((TRACE_ROWS, 3)), params=params, config=HW)
+    units = cf.cg.region_plan.fit_units()
+    assert all(kind == "region" for kind, _ in units)
+    leaves = tuple(_shape(l.shape, one_chip)
+                   for l in jax.tree_util.tree_leaves(params))
+    rows = 8 * cf.config.block
+    coords = _shape((rows, 3), one_chip)
+    text = jax.jit(cf._stream_vg).lower(leaves, coords,
+                                        coords).compile().as_text()
+    assert text.count("tpu_custom_call") == 2 * len(units)
+
+
+def test_bank_region_with_head_slices_compiles(seed_siren, one_chip,
+                                               compiled_kernels):
+    """The five-filter order-2 bank: its heads' column slices are region
+    steps, so one region megakernel holds the tower and the heads; that
+    region, with its lane-offset slices, lowers for Mosaic."""
+    from repro.inr.filters import filter_bank
+    _, _, f = seed_siren
+    bank = filter_bank(f, ["identity", "blur", "edge", "laplacian",
+                           "sharpen"], jnp.zeros((TRACE_ROWS, 2)),
+                       alpha=0.15, config=HW)
+    cg = bank.cg
+    assert any(s[0] == "slice" for r in cg.region_plan.fused_regions()
+               for s in r.spec.steps)
+    request = _shape((2048, cg.config.block, 2), one_chip)
+    text = jax.jit(cg._make_chunk_fn()).lower(request).compile().as_text()
+    assert "%region_fwd." in text
